@@ -42,6 +42,10 @@ def run():
     jit_ref_fused = jax.jit(ref.fused_update)
     jit_ref_q = jax.jit(ref.quantize_q8)
 
+    # compiled Mosaic kernels on a TPU; the interpreter anywhere else
+    interp = jax.default_backend() != "tpu"
+    mode, note = (("interp", "correctness mode") if interp
+                  else ("mosaic", "compiled"))
     rows = [
         ["oracle_per_client_align", _time(jit_ref_align, u, r),
          f"C={C},R={R}"],
@@ -49,15 +53,12 @@ def run():
         ["oracle_fused_update", _time(jit_ref_fused, p, u, w),
          "agg+apply fused"],
         ["oracle_quantize_q8", _time(jit_ref_q, g), "4x bytes saved"],
-        ["pallas_interp_align", _time(
-            lambda: sa.per_client_sign_align(u, r, interpret=True)),
-         "correctness mode"],
-        ["pallas_interp_agg", _time(
-            lambda: ma.masked_agg(u, w, interpret=True)),
-         "correctness mode"],
-        ["pallas_interp_quant", _time(
-            lambda: qz.quantize_q8(g, interpret=True)),
-         "correctness mode"],
+        [f"pallas_{mode}_align", _time(
+            lambda: sa.per_client_sign_align(u, r, interpret=interp)), note],
+        [f"pallas_{mode}_agg", _time(
+            lambda: ma.masked_agg(u, w, interpret=interp)), note],
+        [f"pallas_{mode}_quant", _time(
+            lambda: qz.quantize_q8(g, interpret=interp)), note],
     ]
     # two-pass (align then agg) vs fused single pass, oracle timing
     def two_pass(p, u, w):

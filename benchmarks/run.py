@@ -24,6 +24,8 @@ import importlib
 import time
 import traceback
 
+from repro.launch import compile_cache
+
 MODULES = [
     "table1_baseline_grid",
     "table2_sota",
@@ -417,6 +419,7 @@ def main(argv=None) -> None:
                          "its numbers join the JSON and the "
                          "--check-against regression guard")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.list:
         list_strategies()
         return

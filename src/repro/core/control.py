@@ -66,7 +66,9 @@ def init_control(num_clients: int, batch_sizes=None, lr_scale=None,
                  arena=None, quantize: bool = False) -> ControlState:
     """Initial state matching the host classes' defaults (all EMAs 1)."""
     n = int(num_clients)
-    ones = jnp.ones((n,), jnp.float32)
+    # a fresh buffer per field: the scanned run donates the whole state,
+    # and one buffer donated under two fields is refused
+    ones = lambda: jnp.ones((n,), jnp.float32)  # noqa: E731
     if batch_sizes is None:
         batch = jnp.full((n,), _POW2_MIN, jnp.int32)
     else:
@@ -77,10 +79,10 @@ def init_control(num_clients: int, batch_sizes=None, lr_scale=None,
     else:
         ef = jnp.zeros((0,), jnp.float32)
     return ControlState(
-        avail=ones, pass_rate=ones, round_time=ones, batch=batch,
-        lr_scale=(ones if lr_scale is None
+        avail=ones(), pass_rate=ones(), round_time=ones(), batch=batch,
+        lr_scale=(ones() if lr_scale is None
                   else jnp.asarray(lr_scale, jnp.float32)),
-        grad_norm=ones, staleness=jnp.zeros((n,), jnp.int32),
+        grad_norm=ones(), staleness=jnp.zeros((n,), jnp.int32),
         has_ckpt=jnp.zeros((n,), bool), ef=ef)
 
 

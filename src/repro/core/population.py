@@ -45,11 +45,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import control, selection
 
-try:                                    # jax <= 0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:                     # jax >= 0.6 top-level export
-    from jax import shard_map as _shard_map
-
 # the (num_clients,)-shaped ControlState fields the kernels shard; the
 # error-feedback arena ``ef`` is cohort-indexed (N+1 dummy-row layout of
 # its own) and stays outside the population kernels.
@@ -196,12 +191,12 @@ def round_update_sharded(state, cohort, *, mesh, failed, active, passed,
         return _round_kernel(lv, cohort, failed, active, passed,
                              round_time, sent, norms, off, ema)
 
-    out = _shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=((P("data"),) * len(_FIELDS),
                   rep, rep, rep, rep, rep, rep, rep),
         out_specs=(P("data"),) * len(_FIELDS),
-        check_rep=False)(leaves, cohort, failed, active, passed,
+        check_vma=False)(leaves, cohort, failed, active, passed,
                          round_time, sent, norms)
     return state._replace(**{f: o[:n] for f, o in zip(_FIELDS, out)})
 
@@ -235,9 +230,9 @@ def sharded_candidates(scores: jnp.ndarray, k: int, frac: float, *,
         gid = i.astype(jnp.int32) + jax.lax.axis_index("data") * per
         return v, gid
 
-    return _shard_map(local, mesh=mesh, in_specs=P("data"),
+    return jax.shard_map(local, mesh=mesh, in_specs=P("data"),
                       out_specs=(P("data"), P("data")),
-                      check_rep=False)(scores)
+                      check_vma=False)(scores)
 
 
 def logical_candidates(scores: jnp.ndarray, k: int, frac: float,

@@ -284,11 +284,13 @@ class WorldState(NamedTuple):
 
 
 def empty_world() -> WorldState:
-    """Structure-compatible placeholder for static worlds (0-width)."""
-    z = jnp.zeros((0,), jnp.float32)
-    s = jnp.zeros((), jnp.float32)
-    return WorldState(live=jnp.zeros((0,), bool), bw_scale=z, lat_scale=z,
-                      drift_amp=s, dropout_scale=s, byz_factor=z)
+    """Structure-compatible placeholder for static worlds (0-width).
+    Every field is its own buffer, so the whole state can be donated."""
+    z = lambda: jnp.zeros((0,), jnp.float32)  # noqa: E731
+    s = lambda: jnp.zeros((), jnp.float32)  # noqa: E731
+    return WorldState(live=jnp.zeros((0,), bool), bw_scale=z(),
+                      lat_scale=z(), drift_amp=s(), dropout_scale=s(),
+                      byz_factor=z())
 
 
 def _byz_factor(scn: ScenarioSpec, n: int) -> jnp.ndarray:
@@ -304,10 +306,10 @@ def init_world(scn: Optional[ScenarioSpec], num_clients: int) -> WorldState:
     if not is_active(scn):
         return empty_world()
     n = int(num_clients)
-    ones = jnp.ones((n,), jnp.float32)
     scale0 = (scn.dropout.scales[0] if scn.dropout is not None else 1.0)
     return WorldState(
-        live=jnp.ones((n,), bool), bw_scale=ones, lat_scale=ones,
+        live=jnp.ones((n,), bool), bw_scale=jnp.ones((n,), jnp.float32),
+        lat_scale=jnp.ones((n,), jnp.float32),
         drift_amp=jnp.float32(0.0), dropout_scale=jnp.float32(scale0),
         byz_factor=_byz_factor(scn, n))
 

@@ -161,7 +161,7 @@ def weighted_sum(u, w, compute_dtype=jnp.float32) -> jnp.ndarray:
     if b == "gpu-pallas":
         return _gpu.masked_agg(u, w)
     out = jnp.einsum("crl,c->rl", u.astype(compute_dtype),
-                     w.astype(compute_dtype))
+                     w.astype(compute_dtype), precision=_ref.F32)
     return out.astype(jnp.float32)
 
 

@@ -32,11 +32,13 @@ BLOCK_R = 8
 def _gather_kernel(oh_ref, src_ref, out_ref):
     oh = oh_ref[...].astype(jnp.float32)               # (K, N)
     src = src_ref[...].astype(jnp.float32)             # (N, BR, LANE)
-    out_ref[...] = jnp.einsum("kn,nrl->krl", oh, src)
+    # f32 contraction on the matrix unit: a bf16 pass would round src
+    out_ref[...] = jnp.einsum("kn,nrl->krl", oh, src,
+                              precision=jax.lax.Precision.HIGHEST)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_r"))
-def onehot_gather(src, onehot, *, interpret: bool = True,
+def onehot_gather(src, onehot, *, interpret: bool,
                   block_r: int = BLOCK_R):
     """src: (N, R, LANE) f32; onehot: (K, N) f32 -> (K, R, LANE) f32."""
     N, R, _ = src.shape

@@ -27,7 +27,7 @@ def _quant_kernel(x_ref, q_ref, s_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_r"))
-def quantize_q8(x, *, interpret: bool = True, block_r: int = BLOCK_R):
+def quantize_q8(x, *, interpret: bool, block_r: int = BLOCK_R):
     """x: (R, LANE) float -> (q int8 (R, LANE), scale f32 (R, 1))."""
     R = x.shape[0]
     grid = (pl.cdiv(R, block_r),)
@@ -52,7 +52,7 @@ def _dequant_kernel(q_ref, s_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_r"))
-def dequantize_q8(q, scale, *, interpret: bool = True, block_r: int = BLOCK_R):
+def dequantize_q8(q, scale, *, interpret: bool, block_r: int = BLOCK_R):
     R = q.shape[0]
     grid = (pl.cdiv(R, block_r),)
     return pl.pallas_call(

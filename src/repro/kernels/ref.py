@@ -9,6 +9,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# f32 contractions everywhere: on a TPU the default precision would take
+# one bf16 pass through the matrix unit, and the oracle would no longer
+# be the f32 reference the kernels are held to
+F32 = jax.lax.Precision.HIGHEST
+
 
 def sign_align_counts(g, r):
     """g: (R, LANE) float; r: (R, LANE) int8 reference signs.
@@ -26,14 +31,16 @@ def per_client_sign_align(u, r):
 
 def masked_agg(u, w):
     """u: (C, R, LANE); w: (C,) pre-normalized weights -> (R, LANE) f32."""
-    return jnp.einsum("crl,c->rl", u.astype(jnp.float32), w.astype(jnp.float32))
+    return jnp.einsum("crl,c->rl", u.astype(jnp.float32), w.astype(jnp.float32),
+                      precision=F32)
 
 
 def fused_update(p, u, w_lr):
     """Fused selective-aggregate + SGD apply (beyond-paper, DESIGN.md §7).
     p: (R, LANE) params; u: (C, R, LANE) updates; w_lr: (C,) = lr·mask·w.
     Returns p - Σ_c w_lr[c]·u[c]."""
-    agg = jnp.einsum("crl,c->rl", u.astype(jnp.float32), w_lr.astype(jnp.float32))
+    agg = jnp.einsum("crl,c->rl", u.astype(jnp.float32), w_lr.astype(jnp.float32),
+                     precision=F32)
     return (p.astype(jnp.float32) - agg).astype(p.dtype)
 
 

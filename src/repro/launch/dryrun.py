@@ -122,8 +122,6 @@ def lower_one(arch: str, shape_name: str, multi_pod: bool, verbose=True):
     compile_s = time.time() - t0
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):       # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_stats = {

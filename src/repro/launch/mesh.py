@@ -20,15 +20,9 @@ ICI_BW = 50e9                 # bytes/s per link
 
 
 def _make_mesh(shape, axes, devices=None):
-    """jax.make_mesh across jax versions: AxisType/axis_types only exist
-    from jax 0.5; on 0.4.x every axis is Auto already."""
-    kwargs = {}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axes)
-    if devices is not None:
-        kwargs["devices"] = devices
-    return jax.make_mesh(shape, axes, **kwargs)
+    """jax.make_mesh with every axis Auto (sharding propagated by XLA)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def fold_mesh_shape(n: int, *, multi_pod: bool = False) -> tuple:

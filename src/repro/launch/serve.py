@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import registry
+from repro.launch import compile_cache
 from repro.models import api
 
 
@@ -137,6 +138,7 @@ def main(argv=None):
                     help="anomaly serving: per-request deadline; expired "
                          "requests answer NaN and count deadline_miss")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     cfg = registry.get_config(args.arch, smoke=args.smoke)
     if cfg.family == "mlp":
         serve_anomaly(cfg, args.batch, requests=args.requests,

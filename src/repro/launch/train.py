@@ -22,6 +22,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import registry
 from repro.core import fl_step
+from repro.launch import compile_cache
 from repro.data import synthetic
 from repro.optim import adamw as optim_mod
 from repro.optim import schedule
@@ -76,6 +77,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = registry.get_config(args.arch, smoke=args.smoke)
     optimizer = optim_mod.for_config(cfg, lr=args.lr)

@@ -211,3 +211,18 @@ def test_staleness_and_checkpoint_counters():
     ctl = control.checkpoint_update(ctl, cohort,
                                     jnp.asarray([True, False]))
     assert np.asarray(ctl.has_ckpt).tolist() == [True, False, False, False]
+
+
+@pytest.mark.parametrize("state", ["control", "empty_world", "world"])
+def test_initial_states_can_be_donated(state):
+    """The scanned run donates ControlState and WorldState whole; two
+    fields sharing one buffer would be refused ("donate the same buffer
+    twice") on every platform that honors donation."""
+    from repro.core import scenario
+    n = 6
+    built = {"control": lambda: control.init_control(n),
+             "empty_world": scenario.empty_world,
+             "world": lambda: scenario.init_world(
+                 scenario.resolve_scenario("churn+flaky-links"), n)}[state]()
+    same = jax.jit(lambda s: s, donate_argnums=0)(built)
+    jax.block_until_ready(same)

@@ -16,7 +16,7 @@ from repro.kernels import ops, ref
 from repro.kernels import quantize as qz
 from repro.kernels import sign_align as sa
 
-SHAPES = [(8, ops.LANE), (16, ops.LANE), (40, ops.LANE)]
+SHAPES = [(8, ops.LANE), (16, ops.LANE), (40, ops.LANE), (49, ops.LANE)]
 DTYPES = [jnp.float32, jnp.bfloat16]
 
 
@@ -32,7 +32,7 @@ def test_sign_align_counts(shape, dtype):
     r = jnp.sign(_rand(jax.random.fold_in(key, 1), shape, jnp.float32)) \
         .astype(jnp.int8)
     np.testing.assert_allclose(
-        np.asarray(sa.sign_align_counts(g, r)),
+        np.asarray(sa.sign_align_counts(g, r, interpret=True)),
         np.asarray(ref.sign_align_counts(g, r)), rtol=1e-6)
 
 
@@ -44,7 +44,7 @@ def test_per_client_sign_align(C, dtype):
     r = jnp.sign(_rand(jax.random.fold_in(key, 2), (16, ops.LANE),
                        jnp.float32)).astype(jnp.int8)
     np.testing.assert_allclose(
-        np.asarray(sa.per_client_sign_align(u, r)),
+        np.asarray(sa.per_client_sign_align(u, r, interpret=True)),
         np.asarray(ref.per_client_sign_align(u, r)), rtol=1e-6)
 
 
@@ -54,7 +54,7 @@ def test_masked_agg(C, shape):
     key = jax.random.PRNGKey(2)
     u = _rand(key, (C,) + shape, jnp.float32)
     w = jax.nn.softmax(_rand(jax.random.fold_in(key, 3), (C,), jnp.float32))
-    np.testing.assert_allclose(np.asarray(ma.masked_agg(u, w)),
+    np.testing.assert_allclose(np.asarray(ma.masked_agg(u, w, interpret=True)),
                                np.asarray(ref.masked_agg(u, w)),
                                rtol=1e-5, atol=1e-6)
 
@@ -69,7 +69,7 @@ def test_onehot_cohort_gather(N, K):
     idx = jax.random.randint(jax.random.fold_in(key, 1), (K,), 0, N)
     onehot = (idx[:, None] == jnp.arange(N)[None, :]).astype(jnp.float32)
     np.testing.assert_array_equal(
-        np.asarray(ga.onehot_gather(src, onehot)),
+        np.asarray(ga.onehot_gather(src, onehot, interpret=True)),
         np.asarray(ref.cohort_gather(src, idx)))
 
 
@@ -79,7 +79,7 @@ def test_fused_update(dtype):
     p = _rand(key, (16, ops.LANE), dtype)
     u = _rand(jax.random.fold_in(key, 4), (4, 16, ops.LANE), jnp.float32)
     w = jnp.array([0.3, 0.0, 0.5, 0.2]) * 0.01
-    got = ma.fused_update(p, u, w)
+    got = ma.fused_update(p, u, w, interpret=True)
     want = ref.fused_update(p, u, w)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -91,11 +91,11 @@ def test_fused_update(dtype):
 def test_quantize_roundtrip(shape):
     key = jax.random.PRNGKey(4)
     x = _rand(key, shape, jnp.float32) * 3.0
-    q, s = qz.quantize_q8(x)
+    q, s = qz.quantize_q8(x, interpret=True)
     q2, s2 = ref.quantize_q8(x)
     np.testing.assert_array_equal(np.asarray(q), np.asarray(q2))
     np.testing.assert_allclose(np.asarray(s), np.asarray(s2), rtol=1e-6)
-    back = qz.dequantize_q8(q, s)
+    back = qz.dequantize_q8(q, s, interpret=True)
     # quantization error bounded by scale/2 per element
     err = np.abs(np.asarray(back) - np.asarray(x))
     assert np.all(err <= np.asarray(s) * 0.51 + 1e-9)
@@ -103,7 +103,7 @@ def test_quantize_roundtrip(shape):
 
 def test_quantize_zero_row_safe():
     x = jnp.zeros((8, ops.LANE), jnp.float32)
-    q, s = qz.quantize_q8(x)
+    q, s = qz.quantize_q8(x, interpret=True)
     assert np.all(np.asarray(q) == 0)
     assert np.all(np.isfinite(np.asarray(s)))
 

@@ -36,11 +36,7 @@ def test_scan_multiplies_by_trip_count():
         (res["flops"], expected, res["while_trips"])
     assert L in res["while_trips"].values()
     # XLA's own cost analysis counts the body once -> analyzer must exceed it
-    # (jax 0.4.x returns a one-dict list; 0.5+ returns the dict)
-    ca = c.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    xla_flops = float(ca.get("flops", 0.0))
+    xla_flops = float(c.cost_analysis().get("flops", 0.0))
     assert res["flops"] > xla_flops
 
 

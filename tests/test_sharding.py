@@ -11,9 +11,8 @@ from repro.launch import sharding
 from repro.models import api
 from repro.optim import adamw as optim_mod
 
-# jax 0.4.37 AbstractMesh takes ((name, size), ...) pairs
-SINGLE = AbstractMesh((("data", 16), ("model", 16)))
-MULTI = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+SINGLE = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_divisible(shapes_tree, specs_tree, mesh, where=""):
