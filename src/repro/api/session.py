@@ -50,6 +50,7 @@ from repro.api import runner as runner_mod
 from repro.api.result import ExperimentResult, RoundRecord
 from repro.api.spec import ExperimentSpec
 from repro.checkpoint.io import CheckpointCorruptError
+from repro.obs import span
 
 CHECKPOINT_FORMAT = 1
 
@@ -226,11 +227,12 @@ class _SimDriver:
         self.sim = runner_mod.build_simulation(spec)
 
     def run_rounds(self, n: int, eval_final: bool = True
-                   ) -> List[RoundRecord]:
+                   ) -> Iterator[RoundRecord]:
+        """Advance n rounds; their records, converted as the session
+        consumes them (inside its ``repro.fl.session_records`` span)."""
         prev = len(self.sim.history)
         self.sim.run(n, eval_final=eval_final)
-        return [runner_mod.record_from_metrics(m)
-                for m in self.sim.history[prev:]]
+        return map(runner_mod.record_from_metrics, self.sim.history[prev:])
 
     def state_dict(self) -> dict:
         return self.sim.state_dict()
@@ -334,6 +336,16 @@ class ExperimentSession:
         return len(self.records)
 
     @property
+    def dispatches(self) -> int:
+        """Compiled calls the engine has made so far: one per scanned
+        dispatch, step or eval (``FederatedSimulation.dispatches``).
+        Only the sim engine counts them."""
+        if self.spec.engine != "sim":
+            raise AttributeError(
+                f"engine={self.spec.engine!r} counts no dispatches")
+        return self._driver.sim.dispatches
+
+    @property
     def stopped(self) -> bool:
         return self._stopped
 
@@ -358,18 +370,27 @@ class ExperimentSession:
             return max(0, int(rounds))
         return max(0, self.spec.rounds - self.rounds_done)
 
+    def _advance(self, n: int, eval_final: bool = True
+                 ) -> List[RoundRecord]:
+        """One engine batch of ``n`` rounds: its records are kept and
+        the callbacks fired."""
+        with span("repro.fl.run"):
+            t0 = time.time()
+            new = self._driver.run_rounds(n, eval_final=eval_final)
+            with span("repro.fl.session_records"):
+                new = list(new)
+                self._wall += time.time() - t0
+                self.records.extend(new)
+                self._fire(new)
+        return new
+
     def run(self, rounds: Optional[int] = None) -> List[RoundRecord]:
         """Advance ``rounds`` more rounds (default: the spec's remaining
         budget) as ONE engine batch and return their records."""
         n = self._remaining(rounds)
         if n == 0 or self._stopped:
             return []
-        t0 = time.time()
-        new = self._driver.run_rounds(n)
-        self._wall += time.time() - t0
-        self.records.extend(new)
-        self._fire(new)
-        return new
+        return self._advance(n)
 
     def stream(self, rounds: Optional[int] = None) -> Iterator[RoundRecord]:
         """Yield records as they are produced. Chunk size follows the
@@ -384,13 +405,8 @@ class ExperimentSession:
         done = 0
         while done < n and not self._stopped:
             step = min(chunk, n - done)
-            t0 = time.time()
-            new = self._driver.run_rounds(step,
-                                          eval_final=(done + step >= n))
-            self._wall += time.time() - t0
-            self.records.extend(new)
+            new = self._advance(step, eval_final=(done + step >= n))
             done += len(new)
-            self._fire(new)
             yield from new
 
     def __iter__(self) -> Iterator[RoundRecord]:

@@ -63,6 +63,7 @@ from repro.core.selection import AdaptiveClientSelector, candidate_mask_np
 from repro.data.loader import ArrayLoader, LoaderPool
 from repro.kernels import arena as arena_mod
 from repro.models import api
+from repro.obs import span
 from repro.optim import adamw as optim_mod
 from repro.topology import engine as topology_mod
 from repro.topology.spec import resolve_topology
@@ -996,29 +997,32 @@ class FederatedSimulation:
             last = start + done + Rg - 1
             prev_acc = (self.history[-1].accuracy if self.history
                         else float("nan"))
-            args = [self._params_mat, ref_mat, self._scan_ref_valid,
-                    self._scan_ctl, self._world_state, self._topo_state,
-                    data, sizes, speed, latency, dropout_p,
-                    self._scan_key, jnp.int32(self._scan_round0),
-                    jnp.asarray([self.sim_time, self.comm_time,
-                                 self.idle_time, self.bytes_sent],
-                                jnp.float32)]
-            if self.fused_eval:
-                # eval rides the scan carry: only the final round of the
-                # whole run() is forced (eval_final), the rest follow
-                # the absolute-round eval_every cadence inside the scan
-                mark = (last if (eval_final
-                                 and last == start + num_rounds - 1)
-                        else -1)
-                args += [jnp.float32(prev_acc), jnp.int32(mark),
-                         self._eval_dev]
-            carry, ms = self._scan_fn(Rg)(*args)
+            with span("repro.fl.args"):
+                args = [self._params_mat, ref_mat, self._scan_ref_valid,
+                        self._scan_ctl, self._world_state, self._topo_state,
+                        data, sizes, speed, latency, dropout_p,
+                        self._scan_key, jnp.int32(self._scan_round0),
+                        jnp.asarray([self.sim_time, self.comm_time,
+                                     self.idle_time, self.bytes_sent],
+                                    jnp.float32)]
+                if self.fused_eval:
+                    # eval rides the scan carry: only the final round of the
+                    # whole run() is forced (eval_final), the rest follow
+                    # the absolute-round eval_every cadence inside the scan
+                    mark = (last if (eval_final
+                                     and last == start + num_rounds - 1)
+                            else -1)
+                    args += [jnp.float32(prev_acc), jnp.int32(mark),
+                             self._eval_dev]
+            with span("repro.fl.launch"):
+                carry, ms = self._scan_fn(Rg)(*args)
             self.dispatches += 1
             (self._params_mat, ref_mat, self._scan_ref_valid,
              self._scan_ctl, self._world_state, self._topo_state,
              *_rest) = carry
             self._params_tree = None          # pytree view now stale
-            ms = {k: np.asarray(v) for k, v in ms.items()}
+            with span("repro.fl.readback"):
+                ms = {k: np.asarray(v) for k, v in ms.items()}
 
             if self.fused_eval:
                 acc_val = None                # accuracy is per-round in ms
@@ -1032,41 +1036,46 @@ class FederatedSimulation:
                                               start + done + Rg))
                            or (eval_final and last == start + num_rounds - 1))
                 if do_eval:
-                    acc_val = float(self._eval(self.params, self._eval_dev))
+                    with span("repro.fl.readback"):
+                        acc_val = float(self._eval(self.params,
+                                                   self._eval_dev))
                     self.dispatches += 1
                 else:
                     acc_val = None
-            for j in range(Rg):
-                is_last = j == Rg - 1
-                self.history.append(RoundMetrics(
-                    round=start + done + j,
-                    sim_time=float(ms["sim_time"][j]),
-                    comm_time=float(ms["comm_time"][j]),
-                    idle_time=float(ms["idle_time"][j]),
-                    bytes_sent=float(ms["bytes_sent"][j]),
-                    updates_applied=int(ms["updates_applied"][j]),
-                    accept_rate=float(ms["accept_rate"][j]),
-                    accuracy=(float(ms["accuracy"][j]) if self.fused_eval
-                              else (acc_val
-                                    if (is_last and acc_val is not None)
-                                    else prev_acc)),
-                    loss=float(ms["loss"][j])))
-            self.server_step += int(ms["updates_applied"].sum())
-            # failure times are only known to round granularity on the
-            # scanned path; log each at its round's start clock
-            starts = [self.sim_time] + [float(t) for t
-                                        in ms["sim_time"][:-1]]
-            for j in range(Rg):
-                self.failure_log.extend([starts[j]]
-                                        * int(ms["n_failures"][j]))
-            self.sim_time = float(ms["sim_time"][-1])
-            self.comm_time = float(ms["comm_time"][-1])
-            self.idle_time = float(ms["idle_time"][-1])
-            self.bytes_sent = float(ms["bytes_sent"][-1])
-            self._scan_round0 += Rg
-            self.round_idx += Rg
+            with span("repro.fl.records"):
+                for j in range(Rg):
+                    is_last = j == Rg - 1
+                    self.history.append(RoundMetrics(
+                        round=start + done + j,
+                        sim_time=float(ms["sim_time"][j]),
+                        comm_time=float(ms["comm_time"][j]),
+                        idle_time=float(ms["idle_time"][j]),
+                        bytes_sent=float(ms["bytes_sent"][j]),
+                        updates_applied=int(ms["updates_applied"][j]),
+                        accept_rate=float(ms["accept_rate"][j]),
+                        accuracy=(float(ms["accuracy"][j]) if self.fused_eval
+                                  else (acc_val
+                                        if (is_last and acc_val is not None)
+                                        else prev_acc)),
+                        loss=float(ms["loss"][j])))
+                self.server_step += int(ms["updates_applied"].sum())
+                # failure times are only known to round granularity on the
+                # scanned path; log each at its round's start clock
+                starts = [self.sim_time] + [float(t) for t
+                                            in ms["sim_time"][:-1]]
+                for j in range(Rg):
+                    self.failure_log.extend([starts[j]]
+                                            * int(ms["n_failures"][j]))
+                self.sim_time = float(ms["sim_time"][-1])
+                self.comm_time = float(ms["comm_time"][-1])
+                self.idle_time = float(ms["idle_time"][-1])
+                self.bytes_sent = float(ms["bytes_sent"][-1])
+                self._scan_round0 += Rg
+                self.round_idx += Rg
             done += Rg
-        self._ref_mat = (ref_mat if bool(self._scan_ref_valid) else None)
+        with span("repro.fl.readback"):
+            valid = bool(self._scan_ref_valid)
+        self._ref_mat = ref_mat if valid else None
         return self.history
 
     # ------------------------------------------------------------------

@@ -232,197 +232,207 @@ def build_scanned_rounds(cfg, opt, arena, st, comm, *, num_clients: int,
         else:
             params_mat, ref_mat, ref_valid, ctl, ws, topo, acc = carry
         sim_t, comm_t, idle_t, bytes_s = acc
-        key = jax.random.fold_in(base_key, r)
-        k_eps, k_pick, k_drop, k_data = jax.random.split(key, 4)
+        # each phase runs under a named scope (fl_*): op metadata that the
+        # device trace carries with every op, so a trace splits the round's
+        # device time by phase; it changes no op (see repro/obs.py)
+        with jax.named_scope("fl_select"):
+            key = jax.random.fold_in(base_key, r)
+            k_eps, k_pick, k_drop, k_data = jax.random.split(key, 4)
 
-        # --- dynamic world: this round's WorldState ---------------------
-        if scn is not None:
-            ws = scenario_mod.world_step(ws, r, scn, N)
-
-        # --- selection: fixed-width top-k cohort ------------------------
-        # (churned-out clients score -inf so they are only picked when
-        # fewer than K clients are live; those slots carry zero weight)
-        if st.grad_norm_selection:
-            gn = (ctl.grad_norm if scn is None
-                  else jnp.where(ws.live, ctl.grad_norm, -jnp.inf))
-            cohort = jnp.argsort(-gn, stable=True)[:K]
-        elif st.selection and K < N:
-            scores = control.score(ctl)
+            # --- dynamic world: this round's WorldState -----------------
             if scn is not None:
-                scores = jnp.where(ws.live, scores, -jnp.inf)
-            # two-stage: the sharded candidate pre-filter runs on the
-            # live-masked scores (candidate_frac=None -> single-stage,
-            # 1.0 -> all-True mask, bit-identical either way)
-            cohort = control.two_stage_select(
-                scores, K, candidate_frac=candidate_frac,
-                candidate_shards=candidate_shards, epsilon=epsilon,
-                eps_u=jax.random.uniform(k_eps, (K,)),
-                pick_u=jax.random.uniform(k_pick, (K,)),
-                live=None if scn is None else ws.live)
-        else:
-            cohort = jnp.arange(K)
-        live_c = (jnp.ones((K,), bool) if scn is None else ws.live[cohort])
-        # --- dropout draws (§IV-C fault model) --------------------------
-        drop_p = dropout_p[cohort]
-        if scn is not None and scn.dropout is not None:
-            drop_p = drop_p * ws.dropout_scale
-        failed = jax.random.uniform(k_drop, (K,)) < drop_p
-        if scn is not None:
-            failed = failed & live_c      # absent clients cannot fail
-        if st.checkpointing:
-            active = live_c
-            delay = jnp.where(
-                failed, jnp.where(ctl.has_ckpt[cohort],
-                                  jnp.float32(recovery_time),
-                                  jnp.float32(restart_time)), 0.0)
-        else:
-            active = ~failed & live_c
-            delay = jnp.zeros((K,), jnp.float32)
+                ws = scenario_mod.world_step(ws, r, scn, N)
 
-        # --- cohort batches: on-device gather + index sampling ----------
-        sz = sizes[cohort]
-        idx = jax.random.randint(k_data, (K, steps_phys, batch_phys), 0,
-                                 sz[:, None, None])
-        batch = {name: leaf[cohort[:, None, None], idx]
-                 for name, leaf in data.items()}
-        if dirs is not None:
-            batch = scenario_mod.apply_drift(batch, ws.drift_amp, dirs,
-                                             drift_label)
+            # --- selection: fixed-width top-k cohort --------------------
+            # (churned-out clients score -inf so they are only picked when
+            # fewer than K clients are live; those slots carry zero weight)
+            if st.grad_norm_selection:
+                gn = (ctl.grad_norm if scn is None
+                      else jnp.where(ws.live, ctl.grad_norm, -jnp.inf))
+                cohort = jnp.argsort(-gn, stable=True)[:K]
+            elif st.selection and K < N:
+                scores = control.score(ctl)
+                if scn is not None:
+                    scores = jnp.where(ws.live, scores, -jnp.inf)
+                # two-stage: the sharded candidate pre-filter runs on the
+                # live-masked scores (candidate_frac=None -> single-stage,
+                # 1.0 -> all-True mask, bit-identical either way)
+                cohort = control.two_stage_select(
+                    scores, K, candidate_frac=candidate_frac,
+                    candidate_shards=candidate_shards, epsilon=epsilon,
+                    eps_u=jax.random.uniform(k_eps, (K,)),
+                    pick_u=jax.random.uniform(k_pick, (K,)),
+                    live=None if scn is None else ws.live)
+            else:
+                cohort = jnp.arange(K)
+            live_c = (jnp.ones((K,), bool) if scn is None else ws.live[cohort])
+            # --- dropout draws (§IV-C fault model) ----------------------
+            drop_p = dropout_p[cohort]
+            if scn is not None and scn.dropout is not None:
+                drop_p = drop_p * ws.dropout_scale
+            failed = jax.random.uniform(k_drop, (K,)) < drop_p
+            if scn is not None:
+                failed = failed & live_c      # absent clients cannot fail
+            if st.checkpointing:
+                active = live_c
+                delay = jnp.where(
+                    failed, jnp.where(ctl.has_ckpt[cohort],
+                                      jnp.float32(recovery_time),
+                                      jnp.float32(restart_time)), 0.0)
+            else:
+                active = ~failed & live_c
+                delay = jnp.zeros((K,), jnp.float32)
 
-        # --- local training: vmap-of-scan over the cohort ---------------
-        params = arena.unpack(params_mat)
-        lr_scale = (ctl.lr_scale[cohort] if st.per_client_lr
-                    else jnp.ones((K,), jnp.float32))
-        deltas, losses = _train_cohort(cfg, opt, arena, params, batch,
-                                       lr_scale)
-        if scn is not None and scn.byzantine is not None:
-            # corruption BEFORE wire compression and θ scoring
-            deltas = deltas * ws.byz_factor[cohort][:, None, None]
-        new_ef = ctl.ef
-        if st.quantize_updates:
-            ef_cohort = arena_ops.cohort_gather(ctl.ef, cohort)
-            restored, residual = compression.compress_cohort(
-                deltas, ef_cohort)
-            new_ef = ctl.ef.at[cohort].set(
-                jnp.where(active[:, None, None], residual, ef_cohort))
-            deltas = restored
-        ctl = ctl._replace(ef=new_ef)
+        with jax.named_scope("fl_gather"):
+            # --- cohort batches: on-device gather + index sampling ------
+            sz = sizes[cohort]
+            idx = jax.random.randint(k_data, (K, steps_phys, batch_phys), 0,
+                                     sz[:, None, None])
+            batch = {name: leaf[cohort[:, None, None], idx]
+                     for name, leaf in data.items()}
+            if dirs is not None:
+                batch = scenario_mod.apply_drift(batch, ws.drift_amp, dirs,
+                                                 drift_label)
 
-        norms = jnp.sqrt(jnp.sum(deltas * deltas, axis=(1, 2)))
-        if theta_on:
-            ratios = alignment.cohort_alignment(deltas, ref_mat, arena.n)
-            passed = jnp.where(ref_valid, ratios >= st.theta, True)
-        else:
-            passed = jnp.ones((K,), bool)
-        sent = active & passed
+        with jax.named_scope("fl_train"):
+            # --- local training: vmap-of-scan over the cohort -----------
+            params = arena.unpack(params_mat)
+            lr_scale = (ctl.lr_scale[cohort] if st.per_client_lr
+                        else jnp.ones((K,), jnp.float32))
+            deltas, losses = _train_cohort(cfg, opt, arena, params, batch,
+                                           lr_scale)
+            if scn is not None and scn.byzantine is not None:
+                # corruption BEFORE wire compression and θ scoring
+                deltas = deltas * ws.byz_factor[cohort][:, None, None]
+        with jax.named_scope("fl_align"):
+            new_ef = ctl.ef
+            if st.quantize_updates:
+                ef_cohort = arena_ops.cohort_gather(ctl.ef, cohort)
+                restored, residual = compression.compress_cohort(
+                    deltas, ef_cohort)
+                new_ef = ctl.ef.at[cohort].set(
+                    jnp.where(active[:, None, None], residual, ef_cohort))
+                deltas = restored
+            ctl = ctl._replace(ef=new_ef)
 
-        # --- event accounting (the engine's timing model, vectorized) ---
-        b_eff = jnp.minimum(
-            (ctl.batch[cohort] if st.dynamic_batch else batch_phys), sz)
-        steps_t = control.local_steps(sz, b_eff, st.local_epochs,
-                                      st.max_samples_per_round)
-        b_eff = b_eff.astype(jnp.float32)
-        steps_f = steps_t.astype(jnp.float32)
-        train_t = ((steps_f * comm.t_launch
-                    + steps_f * b_eff * comm.t_sample)
-                   / jnp.maximum(speed[cohort], 1e-3))
-        msg_bytes = jnp.where(sent, payload, beacon)
-        if scn is not None and scn.links is not None:
-            # link-quality walk re-prices this round's transfer
-            transfer = (latency[cohort] * ws.lat_scale[cohort]
-                        + msg_bytes / (comm.bandwidth
-                                       * ws.bw_scale[cohort]))
-        else:
-            transfer = latency[cohort] + msg_bytes / comm.bandwidth
-        arrive = delay + train_t + transfer          # rel. to round start
-        n_active = active.sum().astype(jnp.int32)
-        n_sent = sent.sum().astype(jnp.int32)
-        comm_t = comm_t + jnp.sum(jnp.where(active, transfer, 0.0))
-        bytes_s = bytes_s + jnp.sum(jnp.where(active, msg_bytes, 0.0))
+            norms = jnp.sqrt(jnp.sum(deltas * deltas, axis=(1, 2)))
+            if theta_on:
+                ratios = alignment.cohort_alignment(deltas, ref_mat, arena.n)
+                passed = jnp.where(ref_valid, ratios >= st.theta, True)
+            else:
+                passed = jnp.ones((K,), bool)
+            sent = active & passed
 
-        # --- aggregation weights: sync barrier / async quorum -----------
-        if sched.is_sync:
-            barrier = jnp.max(jnp.where(active, arrive, -jnp.inf))
-            sim_t = jnp.where(n_active > 0, sim_t + barrier, sim_t)
-            idle_t = idle_t + jnp.sum(
-                jnp.where(active, barrier - arrive, 0.0))
-            w = sent.astype(jnp.float32) \
-                / jnp.maximum(n_sent.astype(jnp.float32), 1.0)
-            updates_applied = n_sent
-        else:
-            t_act = jnp.where(active, arrive, jnp.inf)
-            q_idx = jnp.maximum(
-                0, jnp.ceil(sched.quorum * n_active.astype(jnp.float32))
-                .astype(jnp.int32) - 1)
-            sim_t = jnp.where(n_active > 0,
-                              sim_t + jnp.sort(t_act)[q_idx], sim_t)
-            rank = jnp.argsort(jnp.argsort(t_act, stable=True),
-                               stable=True)
-            tau = jnp.maximum(0, rank - q_idx)
-            alphas = aggregation.staleness_weight(tau, sched.alpha0)
-            applied_mask = sent
-            if sched.max_staleness is not None:
-                # semi-async: bounded staleness — arrivals beyond the
-                # cutoff transmitted (bytes already charged) but dropped
-                applied_mask = sent & (tau <= sched.max_staleness)
-            n_applied = applied_mask.sum().astype(jnp.int32)
-            w = jnp.where(applied_mask, alphas, 0.0) \
-                / jnp.maximum(n_applied.astype(jnp.float32), 1.0)
-            updates_applied = n_applied
+        with jax.named_scope("fl_aggregate"):
+            # --- event accounting (the engine's timing model, vectorized) ---
+            b_eff = jnp.minimum(
+                (ctl.batch[cohort] if st.dynamic_batch else batch_phys), sz)
+            steps_t = control.local_steps(sz, b_eff, st.local_epochs,
+                                          st.max_samples_per_round)
+            b_eff = b_eff.astype(jnp.float32)
+            steps_f = steps_t.astype(jnp.float32)
+            train_t = ((steps_f * comm.t_launch
+                        + steps_f * b_eff * comm.t_sample)
+                       / jnp.maximum(speed[cohort], 1e-3))
+            msg_bytes = jnp.where(sent, payload, beacon)
+            if scn is not None and scn.links is not None:
+                # link-quality walk re-prices this round's transfer
+                transfer = (latency[cohort] * ws.lat_scale[cohort]
+                            + msg_bytes / (comm.bandwidth
+                                           * ws.bw_scale[cohort]))
+            else:
+                transfer = latency[cohort] + msg_bytes / comm.bandwidth
+            arrive = delay + train_t + transfer          # rel. to round start
+            n_active = active.sum().astype(jnp.int32)
+            n_sent = sent.sum().astype(jnp.int32)
+            comm_t = comm_t + jnp.sum(jnp.where(active, transfer, 0.0))
+            bytes_s = bytes_s + jnp.sum(jnp.where(active, msg_bytes, 0.0))
 
-        # --- one weighted arena sum applies the round ------------------
-        new_mat = params_mat + arena_ops.weighted_sum(deltas, w)
-        applied = updates_applied > 0
-        if theta_on:
-            sref = arena.sign_ref(new_mat, params_mat)
-            ref_mat = jnp.where(applied, sref, ref_mat)
-            ref_valid = ref_valid | applied
-        params_mat = new_mat
+            # --- aggregation weights: sync barrier / async quorum -------
+            if sched.is_sync:
+                barrier = jnp.max(jnp.where(active, arrive, -jnp.inf))
+                sim_t = jnp.where(n_active > 0, sim_t + barrier, sim_t)
+                idle_t = idle_t + jnp.sum(
+                    jnp.where(active, barrier - arrive, 0.0))
+                w = sent.astype(jnp.float32) \
+                    / jnp.maximum(n_sent.astype(jnp.float32), 1.0)
+                updates_applied = n_sent
+            else:
+                t_act = jnp.where(active, arrive, jnp.inf)
+                q_idx = jnp.maximum(
+                    0, jnp.ceil(sched.quorum * n_active.astype(jnp.float32))
+                    .astype(jnp.int32) - 1)
+                sim_t = jnp.where(n_active > 0,
+                                  sim_t + jnp.sort(t_act)[q_idx], sim_t)
+                rank = jnp.argsort(jnp.argsort(t_act, stable=True),
+                                   stable=True)
+                tau = jnp.maximum(0, rank - q_idx)
+                alphas = aggregation.staleness_weight(tau, sched.alpha0)
+                applied_mask = sent
+                if sched.max_staleness is not None:
+                    # semi-async: bounded staleness — arrivals beyond the
+                    # cutoff transmitted (bytes already charged) but dropped
+                    applied_mask = sent & (tau <= sched.max_staleness)
+                n_applied = applied_mask.sum().astype(jnp.int32)
+                w = jnp.where(applied_mask, alphas, 0.0) \
+                    / jnp.maximum(n_applied.astype(jnp.float32), 1.0)
+                updates_applied = n_applied
 
-        # --- hierarchical topology: leaf accumulation + due syncs -------
-        if topology is not None:
-            topo = topology.step(topo, r, deltas, w,
-                                 topology.pod_of[cohort])
+            # --- one weighted arena sum applies the round --------------
+            new_mat = params_mat + arena_ops.weighted_sum(deltas, w)
+            applied = updates_applied > 0
+            if theta_on:
+                sref = arena.sign_ref(new_mat, params_mat)
+                ref_mat = jnp.where(applied, sref, ref_mat)
+                ref_valid = ref_valid | applied
+            params_mat = new_mat
 
-        # --- control-plane transitions (core/control.py) ----------------
-        ctl = control.observe_round(ctl, cohort, failed=failed,
-                                    active=active, passed=sent,
-                                    round_time=arrive, ema=ema)
-        ctl = control.grad_norm_update(ctl, cohort, norms, active)
-        if st.per_client_lr:
-            ctl = control.lr_scale_update(ctl, cohort, norms, active)
-        if st.dynamic_batch:
-            ctl = control.batch_feedback(ctl, cohort, arrive, active)
-        if st.checkpointing:
-            ctl = control.checkpoint_update(ctl, cohort, active)
-        ctl = control.staleness_update(ctl, cohort, sent)
+            # --- hierarchical topology: leaf accumulation + due syncs ---
+            if topology is not None:
+                topo = topology.step(topo, r, deltas, w,
+                                     topology.pod_of[cohort])
 
-        loss_mean = (jnp.sum(jnp.where(active, losses, 0.0))
-                     / jnp.maximum(n_active.astype(jnp.float32), 1.0))
-        # under churn the paper's acceptance-rate denominator is the
-        # participating cohort (the host engines' len(selected)), not
-        # the static cohort width
-        denom = (jnp.float32(K) if scn is None or scn.churn is None
-                 else jnp.maximum(live_c.sum().astype(jnp.float32), 1.0))
-        metrics = {
-            "sim_time": sim_t, "comm_time": comm_t, "idle_time": idle_t,
-            "bytes_sent": bytes_s,
-            "updates_applied": updates_applied,
-            "accept_rate": (n_sent.astype(jnp.float32) / denom),
-            "loss": loss_mean,
-            "n_failures": failed.sum().astype(jnp.int32),
-        }
-        acc = jnp.stack([sim_t, comm_t, idle_t, bytes_s])
+        with jax.named_scope("fl_control"):
+            # --- control-plane transitions (core/control.py) ------------
+            ctl = control.observe_round(ctl, cohort, failed=failed,
+                                        active=active, passed=sent,
+                                        round_time=arrive, ema=ema)
+            ctl = control.grad_norm_update(ctl, cohort, norms, active)
+            if st.per_client_lr:
+                ctl = control.lr_scale_update(ctl, cohort, norms, active)
+            if st.dynamic_batch:
+                ctl = control.batch_feedback(ctl, cohort, arrive, active)
+            if st.checkpointing:
+                ctl = control.checkpoint_update(ctl, cohort, active)
+            ctl = control.staleness_update(ctl, cohort, sent)
+
+            loss_mean = (jnp.sum(jnp.where(active, losses, 0.0))
+                         / jnp.maximum(n_active.astype(jnp.float32), 1.0))
+            # under churn the paper's acceptance-rate denominator is the
+            # participating cohort (the host engines' len(selected)), not
+            # the static cohort width
+            denom = (jnp.float32(K) if scn is None or scn.churn is None
+                     else jnp.maximum(live_c.sum().astype(jnp.float32), 1.0))
+            metrics = {
+                "sim_time": sim_t, "comm_time": comm_t, "idle_time": idle_t,
+                "bytes_sent": bytes_s,
+                "updates_applied": updates_applied,
+                "accept_rate": (n_sent.astype(jnp.float32) / denom),
+                "loss": loss_mean,
+                "n_failures": failed.sum().astype(jnp.int32),
+            }
+            acc = jnp.stack([sim_t, comm_t, idle_t, bytes_s])
 
         # --- fused eval: accuracy joins the scan carry ------------------
         if eval_fn is not None:
-            do = (r % E == 0) | (r == eval_mark)
-            prev_acc = jax.lax.cond(
-                do,
-                lambda m: jnp.asarray(
-                    eval_fn(arena.unpack(m), eval_data), jnp.float32),
-                lambda m: prev_acc,
-                params_mat)
+            with jax.named_scope("fl_eval"):
+                do = (r % E == 0) | (r == eval_mark)
+                prev_acc = jax.lax.cond(
+                    do,
+                    lambda m: jnp.asarray(
+                        eval_fn(arena.unpack(m), eval_data), jnp.float32),
+                    lambda m: prev_acc,
+                    params_mat)
             metrics["accuracy"] = prev_acc
             return (params_mat, ref_mat, ref_valid, ctl, ws, topo, acc,
                     prev_acc), metrics

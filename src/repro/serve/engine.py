@@ -69,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import mlp_detector
+from repro.obs import span
 from repro.serve.swap import ModelSlot
 
 
@@ -273,17 +274,18 @@ class ServeEngine:
         flow (micro-batching regroups them into buckets). With
         ``best_effort=True`` shed rows are skipped (their ids omitted)
         instead of raising :class:`QueueFullError`."""
-        out = []
-        for row in np.asarray(X, np.float32):
-            rid = self._admit(row, deadline_ms)
-            if rid is None and not best_effort:
-                raise QueueFullError(
-                    f"queue at limit ({self.queue_limit}) — request shed "
-                    f"after {len(out)} rows (best_effort=True skips "
-                    "instead)")
-            if rid is not None:
-                out.append(rid)
-        return out
+        with span("repro.serve.submit"):
+            out = []
+            for row in np.asarray(X, np.float32):
+                rid = self._admit(row, deadline_ms)
+                if rid is None and not best_effort:
+                    raise QueueFullError(
+                        f"queue at limit ({self.queue_limit}) — request "
+                        f"shed after {len(out)} rows (best_effort=True "
+                        "skips instead)")
+                if rid is not None:
+                    out.append(rid)
+            return out
 
     @property
     def pending(self) -> int:
@@ -335,59 +337,62 @@ class ServeEngine:
         failure re-queues the batch at the front and returns the
         expired responses only (retry on the next pump)."""
         t_now = self.now()
-        with self._lock:
-            depth = len(self._queue)
-            self._depth_ema = (self.ema_decay * self._depth_ema
-                               + (1.0 - self.ema_decay) * depth)
-            if self.queue_limit is not None:
-                if (not self._degraded and self._depth_ema
-                        > self.degrade_high * self.queue_limit):
-                    self._degraded = True
-                elif (self._degraded and self._depth_ema
-                        < self.degrade_low * self.queue_limit):
-                    self._degraded = False
-            reqs, expired = [], []
-            while self._queue and len(reqs) < self.max_batch:
-                entry = self._queue.popleft()
-                if entry[3] is not None and t_now > entry[3]:
-                    expired.append(entry)
-                else:
-                    reqs.append(entry)
-            self._inflight += len(reqs)
-            degraded = self._degraded
-            use_monitor = self.monitor is not None and not degraded
-            if reqs and degraded:
-                self._degraded_pumps += 1
+        with span("repro.serve.take"):
+            with self._lock:
+                depth = len(self._queue)
+                self._depth_ema = (self.ema_decay * self._depth_ema
+                                   + (1.0 - self.ema_decay) * depth)
+                if self.queue_limit is not None:
+                    if (not self._degraded and self._depth_ema
+                            > self.degrade_high * self.queue_limit):
+                        self._degraded = True
+                    elif (self._degraded and self._depth_ema
+                            < self.degrade_low * self.queue_limit):
+                        self._degraded = False
+                reqs, expired = [], []
+                while self._queue and len(reqs) < self.max_batch:
+                    entry = self._queue.popleft()
+                    if entry[3] is not None and t_now > entry[3]:
+                        expired.append(entry)
+                    else:
+                        reqs.append(entry)
+                self._inflight += len(reqs)
+                degraded = self._degraded
+                use_monitor = self.monitor is not None and not degraded
+                if reqs and degraded:
+                    self._degraded_pumps += 1
         out = self._expired_responses(expired, t_now)
         if not reqs:
             return out
 
         t0 = self.now()
-        params, meta = self.slot.acquire()
-        if self._last_version is not None \
-                and meta.version != self._last_version:
-            self._swaps_seen += 1
-        self._last_version = meta.version
-        n = len(reqs)
-        bucket = self.bucket_for(n)
-        xpad = np.zeros((bucket, self.cfg.num_features), np.float32)
-        for i, (_rid, x, _t, _dl) in enumerate(reqs):
-            xpad[i] = x
+        with span("repro.serve.pad"):
+            params, meta = self.slot.acquire()
+            if self._last_version is not None \
+                    and meta.version != self._last_version:
+                self._swaps_seen += 1
+            self._last_version = meta.version
+            n = len(reqs)
+            bucket = self.bucket_for(n)
+            xpad = np.zeros((bucket, self.cfg.num_features), np.float32)
+            for i, (_rid, x, _t, _dl) in enumerate(reqs):
+                xpad[i] = x
         fired = False
         try:
             if self.injector is not None:
                 self.injector.check("scorer")
-            if use_monitor:
-                mask = np.zeros((bucket,), np.float32)
-                mask[:n] = 1.0
-                probs, scores, mstate, stat = self._scorer_mon(
-                    params, self.monitor.state, self.monitor.reference,
-                    jnp.asarray(xpad), jnp.asarray(mask))
-            else:
-                probs, scores = self._scorer_plain(params,
-                                                   jnp.asarray(xpad))
-            probs = np.asarray(probs)        # device sync point
-            scores = np.asarray(scores)
+            with span("repro.serve.score"):
+                if use_monitor:
+                    mask = np.zeros((bucket,), np.float32)
+                    mask[:n] = 1.0
+                    probs, scores, mstate, stat = self._scorer_mon(
+                        params, self.monitor.state, self.monitor.reference,
+                        jnp.asarray(xpad), jnp.asarray(mask))
+                else:
+                    probs, scores = self._scorer_plain(params,
+                                                       jnp.asarray(xpad))
+                probs = np.asarray(probs)        # device sync point
+                scores = np.asarray(scores)
         except Exception:
             # graceful absorption: the batch goes BACK to the front of
             # the queue in order — accepted requests are never lost to a
@@ -406,28 +411,29 @@ class ServeEngine:
         if use_monitor:
             fired = self.monitor.observe(mstate, stat)
 
-        lats = []
-        for i, (rid, _x, t_in, _dl) in enumerate(reqs):
-            lat = t_done - t_in
-            lats.append(lat)
-            out.append(Response(request_id=rid, probs=probs[i],
-                                score=float(scores[i]),
-                                model_version=meta.version, latency=lat))
-        dt = t_done - t0
-        with self._lock:
-            self._dispatch_failures = 0
-            self.served += n
-            self._inflight -= n
-            self._busy += dt
-            self._latencies.extend(lats)
-            self._versions_served.add(meta.version)
-            b = self._by_bucket.setdefault(
-                bucket, {"count": 0, "rows": 0, "seconds": 0.0,
-                         "latencies": []})
-            b["count"] += 1
-            b["rows"] += n
-            b["seconds"] += dt
-            b["latencies"].extend(lats)
+        with span("repro.serve.respond"):
+            lats = []
+            for i, (rid, _x, t_in, _dl) in enumerate(reqs):
+                lat = t_done - t_in
+                lats.append(lat)
+                out.append(Response(request_id=rid, probs=probs[i],
+                                    score=float(scores[i]),
+                                    model_version=meta.version, latency=lat))
+            dt = t_done - t0
+            with self._lock:
+                self._dispatch_failures = 0
+                self.served += n
+                self._inflight -= n
+                self._busy += dt
+                self._latencies.extend(lats)
+                self._versions_served.add(meta.version)
+                b = self._by_bucket.setdefault(
+                    bucket, {"count": 0, "rows": 0, "seconds": 0.0,
+                             "latencies": []})
+                b["count"] += 1
+                b["rows"] += n
+                b["seconds"] += dt
+                b["latencies"].extend(lats)
         if fired and self.on_trigger is not None:
             self.on_trigger()
         return out
