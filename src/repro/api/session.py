@@ -346,6 +346,17 @@ class ExperimentSession:
         return self._driver.sim.dispatches
 
     @property
+    def overlapped_dispatches(self) -> int:
+        """Scanned dispatches enqueued while an earlier dispatch's
+        metrics were still on their way back: all but the first of each
+        ``run`` call; none in ``stream``, which advances one dispatch a
+        chunk. Only the sim engine counts them."""
+        if self.spec.engine != "sim":
+            raise AttributeError(
+                f"engine={self.spec.engine!r} counts no dispatches")
+        return self._driver.sim.overlapped_dispatches
+
+    @property
     def stopped(self) -> bool:
         return self._stopped
 

@@ -220,6 +220,9 @@ class FederatedSimulation:
                 "device-side, so the population must be resident — drop "
                 "rounds_per_dispatch for lazy worlds")
         self.dispatches = 0           # compiled-call count (bench metric)
+        # scanned dispatches enqueued while an earlier one's metrics were
+        # still on their way back (_run_scanned's look-ahead)
+        self.overlapped_dispatches = 0
 
         # --- dynamic-world scenario (core/scenario.py) --------------------
         # None / inactive -> the world stays frozen at round 0 and every
@@ -984,6 +987,13 @@ class FederatedSimulation:
 
     def _run_scanned(self, num_rounds: int,
                      eval_final: bool = True) -> List[RoundMetrics]:
+        """Runs ``num_rounds`` rounds as scanned dispatches, one dispatch
+        ahead of the host: dispatch k+1 is chained on dispatch k's device
+        carry (parameters, θ reference, control, world and topology
+        state, accounting, fused accuracy) and enqueued before the host
+        waits for k's metrics, whose copy back starts as k is launched.
+        Every dispatch's records are in ``history``, in round order, when
+        this returns."""
         data, sizes, speed, latency, dropout_p = self._scan_setup()
         R = self.rounds_per_dispatch
         ref_mat = self._ref_mat
@@ -991,92 +1001,124 @@ class FederatedSimulation:
             ref_mat = jnp.where(jnp.asarray(self._arena.valid_mask()),
                                 jnp.int8(0), jnp.int8(-2))
         start = self.round_idx   # absolute round labels across run() calls
-        done = 0
-        while done < num_rounds:
-            Rg = min(R, num_rounds - done)
-            last = start + done + Rg - 1
-            prev_acc = (self.history[-1].accuracy if self.history
-                        else float("nan"))
+        end = start + num_rounds
+        round0 = self._scan_round0 - start   # scan round of round 0
+        # the first dispatch's accounting (and fused accuracy) come from
+        # the host: current here, and what load_state_dict set; later
+        # dispatches take them from the carry. Host values are NumPy
+        # scalars and arrays, copied to the device rather than run as
+        # programs of their own; the carry values are put there as
+        # (uncommitted) device arrays, like the carry's, so every dispatch
+        # hits one cached signature.
+        acc = jax.device_put(np.asarray(
+            [self.sim_time, self.comm_time, self.idle_time,
+             self.bytes_sent], np.float32))
+        if self.fused_eval:
+            prev_acc = jax.device_put(np.float32(
+                self.history[-1].accuracy if self.history
+                else float("nan")))
+        pending = None           # the launched dispatch not yet recorded
+        for first in range(start, end, R):
+            Rg = min(R, end - first)
+            last = first + Rg - 1
             with span("repro.fl.args"):
                 args = [self._params_mat, ref_mat, self._scan_ref_valid,
                         self._scan_ctl, self._world_state, self._topo_state,
                         data, sizes, speed, latency, dropout_p,
-                        self._scan_key, jnp.int32(self._scan_round0),
-                        jnp.asarray([self.sim_time, self.comm_time,
-                                     self.idle_time, self.bytes_sent],
-                                    jnp.float32)]
+                        self._scan_key, np.int32(round0 + first), acc]
                 if self.fused_eval:
                     # eval rides the scan carry: only the final round of the
                     # whole run() is forced (eval_final), the rest follow
                     # the absolute-round eval_every cadence inside the scan
-                    mark = (last if (eval_final
-                                     and last == start + num_rounds - 1)
-                            else -1)
-                    args += [jnp.float32(prev_acc), jnp.int32(mark),
-                             self._eval_dev]
-            with span("repro.fl.launch"):
-                carry, ms = self._scan_fn(Rg)(*args)
+                    mark = last if (eval_final and last == end - 1) else -1
+                    args += [prev_acc, np.int32(mark), self._eval_dev]
+            try:
+                with span("repro.fl.launch"):
+                    carry, ms = self._scan_fn(Rg)(*args)
+            except BaseException:
+                if pending is not None:   # history stays whole to here
+                    self._scan_records(*pending)
+                raise
             self.dispatches += 1
+            self.overlapped_dispatches += pending is not None
             (self._params_mat, ref_mat, self._scan_ref_valid,
              self._scan_ctl, self._world_state, self._topo_state,
-             *_rest) = carry
-            self._params_tree = None          # pytree view now stale
-            with span("repro.fl.readback"):
-                ms = {k: np.asarray(v) for k, v in ms.items()}
-
+             acc, *tail) = carry
             if self.fused_eval:
-                acc_val = None                # accuracy is per-round in ms
-            else:
-                # evaluate once per dispatch (at its last round) when the
-                # eval cadence lands inside the dispatch or the run ends —
-                # cadence over the ABSOLUTE round index, so a resumed
-                # session keeps the uninterrupted run's eval rounds
-                do_eval = (any(r % self.eval_every == 0
-                               for r in range(start + done,
-                                              start + done + Rg))
-                           or (eval_final and last == start + num_rounds - 1))
-                if do_eval:
-                    with span("repro.fl.readback"):
-                        acc_val = float(self._eval(self.params,
-                                                   self._eval_dev))
-                    self.dispatches += 1
-                else:
-                    acc_val = None
-            with span("repro.fl.records"):
-                for j in range(Rg):
-                    is_last = j == Rg - 1
-                    self.history.append(RoundMetrics(
-                        round=start + done + j,
-                        sim_time=float(ms["sim_time"][j]),
-                        comm_time=float(ms["comm_time"][j]),
-                        idle_time=float(ms["idle_time"][j]),
-                        bytes_sent=float(ms["bytes_sent"][j]),
-                        updates_applied=int(ms["updates_applied"][j]),
-                        accept_rate=float(ms["accept_rate"][j]),
-                        accuracy=(float(ms["accuracy"][j]) if self.fused_eval
-                                  else (acc_val
-                                        if (is_last and acc_val is not None)
-                                        else prev_acc)),
-                        loss=float(ms["loss"][j])))
-                self.server_step += int(ms["updates_applied"].sum())
-                # failure times are only known to round granularity on the
-                # scanned path; log each at its round's start clock
-                starts = [self.sim_time] + [float(t) for t
-                                            in ms["sim_time"][:-1]]
-                for j in range(Rg):
-                    self.failure_log.extend([starts[j]]
-                                            * int(ms["n_failures"][j]))
-                self.sim_time = float(ms["sim_time"][-1])
-                self.comm_time = float(ms["comm_time"][-1])
-                self.idle_time = float(ms["idle_time"][-1])
-                self.bytes_sent = float(ms["bytes_sent"][-1])
-                self._scan_round0 += Rg
-                self.round_idx += Rg
-            done += Rg
-        with span("repro.fl.readback"):
-            valid = bool(self._scan_ref_valid)
-        self._ref_mat = ref_mat if valid else None
+                (prev_acc,) = tail
+            self._params_tree = None          # pytree view now stale
+            for v in ms.values():
+                v.copy_to_host_async()
+            final = last == end - 1
+            if final:
+                self._scan_ref_valid.copy_to_host_async()
+
+            acc_val = None
+            # evaluate once per dispatch (at its last round) when the eval
+            # cadence lands inside the dispatch or the run ends — cadence
+            # over the ABSOLUTE round index, so a resumed session keeps the
+            # uninterrupted run's eval rounds. It reads this dispatch's
+            # parameters, so it runs before the next dispatch takes them.
+            if not self.fused_eval and (
+                    any(r % self.eval_every == 0
+                        for r in range(first, last + 1))
+                    or (eval_final and final)):
+                with span("repro.fl.readback"):
+                    acc_val = float(self._eval(self.params,
+                                               self._eval_dev))
+                self.dispatches += 1
+            if pending is not None:
+                self._scan_records(*pending)
+            pending = (first, ms, acc_val, ref_mat if final else None)
+        if pending is not None:
+            self._scan_records(*pending)
         return self.history
+
+    def _scan_records(self, first: int, ms, acc_val: Optional[float],
+                      ref_mat) -> None:
+        """Appends one dispatch's rounds (from ``first``) to ``history``
+        and advances the host clocks; ``ms`` is its metric arrays, already
+        copying to the host. The call's last dispatch also passes its θ
+        reference, kept if the device marked it valid."""
+        with span("repro.fl.readback"):
+            ms = {k: np.asarray(v) for k, v in ms.items()}
+            if ref_mat is not None:
+                valid = bool(self._scan_ref_valid)
+        Rg = len(ms["sim_time"])
+        prev_acc = (self.history[-1].accuracy if self.history
+                    else float("nan"))
+        with span("repro.fl.records"):
+            for j in range(Rg):
+                is_last = j == Rg - 1
+                self.history.append(RoundMetrics(
+                    round=first + j,
+                    sim_time=float(ms["sim_time"][j]),
+                    comm_time=float(ms["comm_time"][j]),
+                    idle_time=float(ms["idle_time"][j]),
+                    bytes_sent=float(ms["bytes_sent"][j]),
+                    updates_applied=int(ms["updates_applied"][j]),
+                    accept_rate=float(ms["accept_rate"][j]),
+                    accuracy=(float(ms["accuracy"][j]) if self.fused_eval
+                              else (acc_val
+                                    if (is_last and acc_val is not None)
+                                    else prev_acc)),
+                    loss=float(ms["loss"][j])))
+            self.server_step += int(ms["updates_applied"].sum())
+            # failure times are only known to round granularity on the
+            # scanned path; log each at its round's start clock
+            starts = [self.sim_time] + [float(t) for t
+                                        in ms["sim_time"][:-1]]
+            for j in range(Rg):
+                self.failure_log.extend([starts[j]]
+                                        * int(ms["n_failures"][j]))
+            self.sim_time = float(ms["sim_time"][-1])
+            self.comm_time = float(ms["comm_time"][-1])
+            self.idle_time = float(ms["idle_time"][-1])
+            self.bytes_sent = float(ms["bytes_sent"][-1])
+            self._scan_round0 += Rg
+            self.round_idx += Rg
+        if ref_mat is not None:
+            self._ref_mat = ref_mat if valid else None
 
     # ------------------------------------------------------------------
     # full-state serialization (ExperimentSession.checkpoint/restore)
@@ -1134,6 +1176,7 @@ class FederatedSimulation:
             "idle_time": self.idle_time, "bytes_sent": self.bytes_sent,
             "server_step": self.server_step,
             "dispatches": self.dispatches,
+            "overlapped_dispatches": self.overlapped_dispatches,
             "history": [dataclasses.asdict(m) for m in self.history],
         }
 
@@ -1213,6 +1256,7 @@ class FederatedSimulation:
         self.bytes_sent = state["bytes_sent"]
         self.server_step = state["server_step"]
         self.dispatches = state["dispatches"]
+        self.overlapped_dispatches = state.get("overlapped_dispatches", 0)
         self.history = [RoundMetrics(**m) for m in state["history"]]
 
     def client_pass_rates(self) -> np.ndarray:

@@ -30,14 +30,14 @@ FL_SCOPES = ["fl_" + x for x in bench_spans.FL_SCOPES]
 
 
 def _spec(**kw):
-    return ExperimentSpec(
+    return ExperimentSpec(**{**dict(
         model="anomaly-mlp-smoke",
         data=DataSpec(n_samples=1200, eval_samples=200),
         world=WorldSpec(num_clients=5, profile="heterogeneous",
                         dropout_p=0.2),
         strategy=get_strategy("ours").build(batch_size=32,
                                             select_fraction=0.8),
-        rounds_per_dispatch=2, fused_eval=True, **kw)
+        rounds_per_dispatch=2, fused_eval=True), **kw})
 
 
 def _traced(tmp_path, fn):
@@ -66,24 +66,26 @@ def test_engine_loop_spans_once_per_call_and_dispatch(tmp_path, how):
         got = _traced(tmp_path, lambda: list(session.stream(4)))
         calls = 2                                   # one chunk a dispatch
     counts = collections.Counter(n for n, _, _ in got)
-    # 2 dispatches; each call's closing θ-reference check is a readback
+    # 2 dispatches; the call's closing θ-reference check reads back with
+    # its last dispatch's metrics
     assert counts == {"repro.fl.run": calls,
                       "repro.fl.session_records": calls,
                       "repro.fl.args": 2, "repro.fl.launch": 2,
-                      "repro.fl.readback": 2 + calls,
-                      "repro.fl.records": 2}
+                      "repro.fl.readback": 2, "repro.fl.records": 2}
     runs = [s for s in got if s[0] == "repro.fl.run"]
     for s in got:
         assert any(_inside(s, r) for r in runs), s
     steps = [n for n, _, _ in got if n != "repro.fl.run"]
-    per = ["repro.fl.args", "repro.fl.launch", "repro.fl.readback",
-           "repro.fl.records"]
+    launch = ["repro.fl.args", "repro.fl.launch"]
+    record = ["repro.fl.readback", "repro.fl.records"]
     if how == "run":
-        assert steps == per * 2 + ["repro.fl.readback",
-                                   "repro.fl.session_records"]
+        # look-ahead: dispatch 2 is launched before dispatch 1's metrics
+        # are waited for
+        assert steps == (launch * 2 + record * 2
+                         + ["repro.fl.session_records"])
     else:
-        assert steps == (per + ["repro.fl.readback",
-                                "repro.fl.session_records"]) * 2
+        assert steps == (launch + record
+                         + ["repro.fl.session_records"]) * 2
 
 
 def test_serve_spans_once_per_submit_and_pump(tmp_path):
@@ -129,8 +131,10 @@ def test_session_counts_its_dispatches():
     assert session.dispatches == 0
     session.run(4)
     assert session.dispatches == 2 == session._driver.sim.dispatches
+    assert session.overlapped_dispatches == 1
     list(session.stream(2))
     assert session.dispatches == 3
+    assert session.overlapped_dispatches == 1       # stream: no look-ahead
     spmd = ExperimentSession.open(ExperimentSpec(
         model="anomaly-mlp-smoke", engine="spmd",
         data=DataSpec(n_samples=1200, eval_samples=200),
@@ -139,3 +143,21 @@ def test_session_counts_its_dispatches():
                                 batch_size=32)))
     with pytest.raises(AttributeError, match="spmd"):
         spmd.dispatches
+    with pytest.raises(AttributeError, match="spmd"):
+        spmd.overlapped_dispatches
+
+
+@pytest.mark.parametrize("calls", [(24, 24, 8), (8, 16, 24)])
+def test_run_overlaps_dispatches_on_one_cached_program(calls):
+    session = ExperimentSession.open(_spec(rounds_per_dispatch=8))
+    sim = session._driver.sim
+    for n in calls:
+        session.run(n)
+    assert session.overlapped_dispatches == session.dispatches - len(calls)
+    # the first dispatch of a call takes its accounting and accuracy from
+    # the host, the others from the carry: one signature all the same
+    assert sim._scan_fns[8]._cache_size() == 1
+    before = session.overlapped_dispatches
+    list(session.stream(16))
+    assert session.overlapped_dispatches == before
+    assert sim._scan_fns[8]._cache_size() == 1

@@ -327,6 +327,47 @@ def test_run_then_more_rounds_continues_numbering():
     assert [r.round for r in s.records] == [0, 1, 2, 3]
 
 
+def _assert_trees_bitwise_equal(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert jax.tree.structure(a) == jax.tree.structure(b)
+    for x, y in zip(la, lb):
+        x, y = np.asarray(x), np.asarray(y)
+        assert x.dtype == y.dtype and x.shape == y.shape
+        assert x.tobytes() == y.tobytes()
+
+
+@pytest.mark.parametrize("kw,rounds", [
+    (dict(fused_eval=True), 24),                       # async quorum + θ
+    (dict(fused_eval=True, scenario="dynamic"), 24),   # churn/links/drift
+    (dict(fused_eval=False), 24),                      # host eval
+    (dict(fused_eval=True), 20),                       # partial last
+], ids=["quorum-theta", "dynamic", "host-eval", "partial"])
+def test_run_look_ahead_matches_stream_bitwise(kw, rounds):
+    # run() enqueues each dispatch before reading the previous one's
+    # metrics back; stream() advances one dispatch a chunk with no
+    # look-ahead and forces the same end-of-run eval. Evals every 5th
+    # round, so dispatches start on carried-forward accuracy.
+    spec = _sim_spec(rounds_per_dispatch=8, rounds=rounds, eval_every=5,
+                     **kw)
+    assert spec.strategy.selection and spec.strategy.dynamic_batch
+    ran = ExperimentSession.open(spec)
+    ran.run(rounds)
+    streamed = ExperimentSession.open(spec)
+    list(streamed.stream(rounds))
+    a, b = ran._driver.sim, streamed._driver.sim
+    assert a.overlapped_dispatches == -(-rounds // 8) - 1   # all but one
+    assert b.overlapped_dispatches == 0
+    _assert_records_equal(a.history, b.history)
+    _assert_records_equal(ran.records, streamed.records)
+    assert a.failure_log == b.failure_log
+    assert a.server_step == b.server_step
+    for name in ("_params_mat", "_ref_mat", "_scan_ref_valid", "_scan_ctl",
+                 "_world_state"):
+        _assert_trees_bitwise_equal(getattr(a, name), getattr(b, name))
+    _assert_trees_bitwise_equal(a.state_dict()["scan"],
+                                b.state_dict()["scan"])
+
+
 def test_run_experiment_is_session_wrapper():
     spec = _sim_spec(rounds=3)
     res = run_experiment(spec)
