@@ -31,6 +31,9 @@ class RoundRecord:
     accept_rate: float
     accuracy: float
     loss: float
+    expert_rows: int = 0     # rows routed to held experts this round, over
+                             # the cohort's training (scanned sim engine;
+                             # 0 for models without an expert layer)
 
 
 ROUND_FIELDS = tuple(f.name for f in dataclasses.fields(RoundRecord))

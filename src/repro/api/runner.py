@@ -92,7 +92,7 @@ def record_from_metrics(m: "ae.RoundMetrics") -> RoundRecord:
                        bytes_sent=m.bytes_sent,
                        updates_applied=m.updates_applied,
                        accept_rate=m.accept_rate, accuracy=m.accuracy,
-                       loss=m.loss)
+                       loss=m.loss, expert_rows=m.expert_rows)
 
 
 # ---------------------------------------------------------------------------

@@ -357,6 +357,16 @@ class ExperimentSession:
         return self._driver.sim.overlapped_dispatches
 
     @property
+    def expert_rows(self) -> int:
+        """Rows routed to the experts this chip holds, over every round
+        the session ran on the scanned engine (0 for models without an
+        expert layer)."""
+        if self.spec.engine != "sim":
+            raise AttributeError(
+                f"engine={self.spec.engine!r} counts no expert rows")
+        return self._driver.sim.expert_rows
+
+    @property
     def stopped(self) -> bool:
         return self._stopped
 

@@ -173,10 +173,11 @@ class ExperimentSpec:
     def resolve_model(self):
         if not isinstance(self.model, str):
             return self.model                  # already an ArchConfig
-        from repro.configs import anomaly_mlp
+        from repro.configs import anomaly_mlp, moonlight_16b_a3b
         named = {"anomaly-mlp": anomaly_mlp.CONFIG,
                  "anomaly-mlp-road": anomaly_mlp.ROAD_CONFIG,
-                 "anomaly-mlp-smoke": anomaly_mlp.SMOKE}
+                 "anomaly-mlp-smoke": anomaly_mlp.SMOKE,
+                 "moonlight-16b-a3b-ep8-smoke": moonlight_16b_a3b.SMOKE}
         if self.model in named:
             return named[self.model]
         from repro.configs import registry

@@ -2,7 +2,10 @@
 
 Every assigned architecture is expressed as an ``ArchConfig``. Families:
   dense   — llama-style decoder (GQA + RoPE + SwiGLU or variants)
-  moe     — dense skeleton with mixture-of-experts FFN (top-k routing)
+  moe     — dense skeleton with mixture-of-experts FFN (top-k routing);
+            with ``attention="mla"`` the DeepSeek-V3 block (latent
+            attention, sigmoid-routed experts beside shared experts, a
+            leading dense prefix), training only
   ssm     — RWKV6 "Finch" (attention-free, data-dependent decay)
   hybrid  — Hymba (parallel attention + mamba heads per layer)
   audio   — Whisper encoder-decoder backbone (conv frontend stubbed)
@@ -34,11 +37,19 @@ class ArchConfig:
     qkv_bias: bool = False
     attention_impl: str = "full"     # full | blockwise (flash-oracle path)
     norm: str = "rmsnorm"            # rmsnorm | layernorm
+    norm_eps: float = 1e-6           # rmsnorm epsilon
     mlp_act: str = "swiglu"          # swiglu | gelu
     rope_theta: float = 10000.0
     rope_fraction: float = 1.0       # partial rotary (stablelm uses 0.25)
     tie_embeddings: bool = False
     sliding_window: Optional[int] = None   # set for long_500k dense variant
+
+    # multi-head latent attention (attention="mla"; DeepSeek-V2/V3) -------
+    attention: str = "gqa"           # gqa | mla
+    kv_lora_rank: int = 0            # width of the compressed KV latent
+    qk_nope_head_dim: int = 0        # per-head query/key width without RoPE
+    qk_rope_head_dim: int = 0        # decoupled RoPE key width (all heads)
+    v_head_dim: int = 0              # per-head value width
 
     # moe ------------------------------------------------------------------
     num_experts: int = 0
@@ -47,6 +58,16 @@ class ArchConfig:
     moe_dispatch: str = "gather"           # gather | scatter (§Perf iter D)
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01        # load-balance loss weight
+    router: str = "softmax"                # softmax | sigmoid (+ correction
+                                           # bias used only to choose)
+    routed_scale: float = 1.0              # routed output scale (sigmoid)
+    router_bias_std: float = 0.0           # seeded correction bias (not
+    router_bias_seed: int = 0              # trained, not federated)
+    shared_experts: int = 0                # always-on experts beside routed
+    dense_layers: int = 0                  # leading dense layers (MoE after)
+    dense_d_ff: int = 0                    # their FFN width
+    experts_held: int = 0                  # experts this chip computes (0:
+    expert_offset: int = 0                 # all); held ids start here
 
     # ssm / hybrid -----------------------------------------------------------
     ssm_state: int = 0               # mamba state size (hymba) / 0
@@ -131,6 +152,19 @@ class ArchConfig:
                 + dd * self.ssm_state + dd + dd * d + 4 * dd
             per_layer = attn + mamba + ffn + 3 * d
             return L * per_layer + emb + d
+        if self.family == "moe" and self.attention == "mla":
+            nope, rope, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                              self.v_head_dim)
+            r = self.kv_lora_rank
+            mla = (d * H * (nope + rope) + d * (r + rope) + r
+                   + r * H * (nope + vd) + H * vd * d)
+            n_moe = L - self.dense_layers
+            held = self.experts_held or self.num_experts
+            experts = ((self.top_k if active_only else held)
+                       + self.shared_experts) * 3 * d * ff
+            dense = self.dense_layers * 3 * d * self.dense_d_ff
+            return (L * (mla + norms) + dense
+                    + n_moe * (experts + d * self.num_experts) + emb + d)
         if self.family in ("moe",):
             e_ffn = self.num_experts * 3 * d * ff
             a_ffn = (self.top_k if active_only else self.num_experts) * 3 * d * ff

@@ -3,8 +3,8 @@ from __future__ import annotations
 
 from repro.configs import (anomaly_mlp, arctic_480b, granite_34b,
                            granite_moe_1b, hymba_1_5b, internvl2_2b,
-                           phi3_mini_3_8b, qwen2_1_5b, rwkv6_7b,
-                           stablelm_1_6b, whisper_tiny)
+                           moonlight_16b_a3b, phi3_mini_3_8b, qwen2_1_5b,
+                           rwkv6_7b, stablelm_1_6b, whisper_tiny)
 from repro.configs.base import ArchConfig
 
 _MODULES = {
@@ -19,9 +19,13 @@ _MODULES = {
     "arctic-480b": arctic_480b,
     "phi3-mini-3.8b": phi3_mini_3_8b,
     "anomaly-mlp": anomaly_mlp,
+    "moonlight-16b-a3b-ep8": moonlight_16b_a3b,
 }
 
-ASSIGNED_ARCHS = [k for k in _MODULES if k != "anomaly-mlp"]
+# federated through the scanned engine only: no prefill/decode path
+TRAIN_ONLY = ("moonlight-16b-a3b-ep8",)
+ASSIGNED_ARCHS = [k for k in _MODULES
+                  if k != "anomaly-mlp" and k not in TRAIN_ONLY]
 
 
 def list_archs():

@@ -156,6 +156,8 @@ class RoundMetrics:
     accept_rate: float
     accuracy: float
     loss: float
+    expert_rows: int = 0     # rows the cohort routed to held experts
+                             # (scanned path; 0 for models without them)
 
 
 class FederatedSimulation:
@@ -223,6 +225,9 @@ class FederatedSimulation:
         # scanned dispatches enqueued while an earlier one's metrics were
         # still on their way back (_run_scanned's look-ahead)
         self.overlapped_dispatches = 0
+        # rows routed to held experts over every scanned round (0 for
+        # models without an expert layer)
+        self.expert_rows = 0
 
         # --- dynamic-world scenario (core/scenario.py) --------------------
         # None / inactive -> the world stays frozen at round 0 and every
@@ -1032,6 +1037,10 @@ class FederatedSimulation:
                     # the absolute-round eval_every cadence inside the scan
                     mark = last if (eval_final and last == end - 1) else -1
                     args += [prev_acc, np.int32(mark), self._eval_dev]
+            # the arena is the state from here on: a pytree view kept from
+            # init or a params read would hold a second copy of the model
+            # through the dispatch
+            self._params_tree = None
             try:
                 with span("repro.fl.launch"):
                     carry, ms = self._scan_fn(Rg)(*args)
@@ -1046,7 +1055,6 @@ class FederatedSimulation:
              acc, *tail) = carry
             if self.fused_eval:
                 (prev_acc,) = tail
-            self._params_tree = None          # pytree view now stale
             for v in ms.values():
                 v.copy_to_host_async()
             final = last == end - 1
@@ -1102,7 +1110,11 @@ class FederatedSimulation:
                               else (acc_val
                                     if (is_last and acc_val is not None)
                                     else prev_acc)),
-                    loss=float(ms["loss"][j])))
+                    loss=float(ms["loss"][j]),
+                    expert_rows=int(ms["expert_rows"][j])
+                    if "expert_rows" in ms else 0))
+            if "expert_rows" in ms:
+                self.expert_rows += int(ms["expert_rows"].sum())
             self.server_step += int(ms["updates_applied"].sum())
             # failure times are only known to round granularity on the
             # scanned path; log each at its round's start clock
@@ -1177,6 +1189,7 @@ class FederatedSimulation:
             "server_step": self.server_step,
             "dispatches": self.dispatches,
             "overlapped_dispatches": self.overlapped_dispatches,
+            "expert_rows": self.expert_rows,
             "history": [dataclasses.asdict(m) for m in self.history],
         }
 
@@ -1257,6 +1270,7 @@ class FederatedSimulation:
         self.server_step = state["server_step"]
         self.dispatches = state["dispatches"]
         self.overlapped_dispatches = state.get("overlapped_dispatches", 0)
+        self.expert_rows = state.get("expert_rows", 0)
         self.history = [RoundMetrics(**m) for m in state["history"]]
 
     def client_pass_rates(self) -> np.ndarray:

@@ -46,31 +46,71 @@ from repro.kernels import arena as arena_ops
 from repro.models import api
 
 
-def _train_cohort(cfg, opt, arena, params, batches, lr_scale):
-    """The cohort's local training as ONE vmap-of-scan — shared by the
-    per-round cohort step and the scanned control plane so the two
-    compiled paths can never drift apart. Returns the per-client deltas
-    packed into the (C, rows, lane) arena and (C,) mean losses."""
+# a cohort whose training state (parameters, momentum and gradients, f32,
+# one copy per slot) would pass this many bytes trains one slot after
+# another instead of as one vmap
+VMAP_COHORT_BYTES = 1 << 30
 
-    def train_one(client_batches, scale):
+
+def cohort_is_sequential(arena, cohort: int) -> bool:
+    """True when the cohort trains slot by slot: its vmapped training
+    state, ``cohort`` × 3 f32 copies of the arena's parameters, would
+    pass ``VMAP_COHORT_BYTES``. The detectors stay far below (a 100-slot
+    fleet cohort holds ~60 MB); a model of 10^8 parameters and more
+    does not fit a vmap of two on a 16 GB chip."""
+    return cohort * 3 * 4 * arena.n > VMAP_COHORT_BYTES
+
+
+def _train_cohort(cfg, opt, arena, params_mat, batches, lr_scale):
+    """The cohort's local training — shared by the per-round cohort step
+    and the scanned control plane so the two compiled paths can never
+    drift apart. Small models train as ONE vmap-of-scan; a model whose
+    cohort would not fit that way (``cohort_is_sequential``) trains one
+    slot after another: each slot unpacks the globals into its own
+    training state and packs its delta as soon as it is done. Returns
+    the per-client deltas packed into the (C, rows, lane) arena, (C,)
+    mean losses, and the model's counters (``api.loss_and_stats``)
+    summed over slots and steps."""
+
+    def train_one(params, client_batches, scale):
         opt_state = opt.init(params)
 
         def step(carry, batch):
             p, s = carry
-            loss, grads = jax.value_and_grad(
-                lambda q: api.loss_fn(q, batch, cfg))(p)
+            (loss, stats), grads = jax.value_and_grad(
+                lambda q: api.loss_and_stats(q, batch, cfg),
+                has_aux=True)(p)
             grads = jax.tree.map(lambda g: g * scale, grads)
             p, s = opt.update(grads, s, p)
-            return (p, s), loss
+            return (p, s), (loss, stats)
 
-        (p, _), losses = jax.lax.scan(step, (params, opt_state),
-                                      client_batches)
-        return p, losses.mean()
+        (p, _), (losses, stats) = jax.lax.scan(
+            step, (params, opt_state), client_batches)
+        return p, losses.mean(), jax.tree.map(jnp.sum, stats)
 
-    new_params, losses = jax.vmap(train_one)(batches, lr_scale)
-    deltas = arena.pack_cohort(jax.tree.map(
-        lambda n, o: (n - o).astype(jnp.float32), new_params, params))
-    return deltas, losses
+    if cohort_is_sequential(arena, lr_scale.shape[0]):
+        mat, deltas, losses, stats = params_mat, [], [], []
+        for c in range(lr_scale.shape[0]):
+            p, loss, counted = train_one(
+                arena.unpack(mat), jax.tree.map(lambda b: b[c], batches),
+                lr_scale[c])
+            # the next slot unpacks its globals only once this delta is
+            # done, so one slot's copy lives at a time; pack(globals) is
+            # params_mat bit for bit
+            d, mat = jax.lax.optimization_barrier(
+                (arena.pack(p) - params_mat, params_mat))
+            deltas.append(d)
+            losses.append(loss)
+            stats.append(counted)
+        deltas, losses = jnp.stack(deltas), jnp.stack(losses)
+        stats = jax.tree.map(lambda *xs: sum(xs), *stats)
+    else:
+        params = arena.unpack(params_mat)
+        new_params, losses, stats = jax.vmap(
+            lambda b, s: train_one(params, b, s))(batches, lr_scale)
+        deltas = arena.pack_cohort(jax.tree.map(
+            lambda n, o: (n - o).astype(jnp.float32), new_params, params))
+    return deltas, losses, jax.tree.map(jnp.sum, stats)
 
 
 def build_cohort_step(cfg, opt, arena, theta=None, quantize: bool = False):
@@ -91,9 +131,8 @@ def build_cohort_step(cfg, opt, arena, theta=None, quantize: bool = False):
     @functools.partial(jax.jit, static_argnames=("has_ref",))
     def cohort_step(params_mat, batches, lr_scale, byz, ref_mat, ef, idx, *,
                     has_ref):
-        params = arena.unpack(params_mat)
-        deltas, losses = _train_cohort(cfg, opt, arena, params, batches,
-                                       lr_scale)
+        deltas, losses, _ = _train_cohort(cfg, opt, arena, params_mat,
+                                          batches, lr_scale)
         if byz is not None:
             deltas = deltas * byz[:, None, None]
         new_ef = ef
@@ -295,12 +334,12 @@ def build_scanned_rounds(cfg, opt, arena, st, comm, *, num_clients: int,
                                                  drift_label)
 
         with jax.named_scope("fl_train"):
-            # --- local training: vmap-of-scan over the cohort -----------
-            params = arena.unpack(params_mat)
+            # --- local training: the cohort's vmap-of-scan (slot by slot
+            # for a model too large to vmap) ----------------------------
             lr_scale = (ctl.lr_scale[cohort] if st.per_client_lr
                         else jnp.ones((K,), jnp.float32))
-            deltas, losses = _train_cohort(cfg, opt, arena, params, batch,
-                                           lr_scale)
+            deltas, losses, counters = _train_cohort(
+                cfg, opt, arena, params_mat, batch, lr_scale)
             if scn is not None and scn.byzantine is not None:
                 # corruption BEFORE wire compression and θ scoring
                 deltas = deltas * ws.byz_factor[cohort][:, None, None]
@@ -420,6 +459,9 @@ def build_scanned_rounds(cfg, opt, arena, st, comm, *, num_clients: int,
                 "accept_rate": (n_sent.astype(jnp.float32) / denom),
                 "loss": loss_mean,
                 "n_failures": failed.sum().astype(jnp.int32),
+                # the model's counters over the whole cohort's training
+                # (expert_rows; none for the detectors)
+                **counters,
             }
             acc = jnp.stack([sim_t, comm_t, idle_t, bytes_s])
 

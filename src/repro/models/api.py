@@ -45,6 +45,16 @@ def loss_fn(params, batch, cfg: ArchConfig):
     return module_for(cfg).loss_fn(params, batch, cfg)
 
 
+def loss_and_stats(params, batch, cfg: ArchConfig):
+    """(loss, stats): the family's counters from the same pass (the
+    expert layer's ``expert_rows``), or an empty
+    dict for families that count nothing."""
+    mod = module_for(cfg)
+    if hasattr(mod, "loss_and_stats"):
+        return mod.loss_and_stats(params, batch, cfg)
+    return mod.loss_fn(params, batch, cfg), {}
+
+
 def prefill(params, batch, cfg: ArchConfig):
     return module_for(cfg).prefill(params, batch, cfg)
 

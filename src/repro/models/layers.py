@@ -54,7 +54,7 @@ def layernorm(x, weight, bias, eps: float = 1e-5):
 def apply_norm(cfg, x, p, prefix=""):
     if cfg.norm == "layernorm":
         return layernorm(x, p[prefix + "w"], p[prefix + "b"])
-    return rmsnorm(x, p[prefix + "w"])
+    return rmsnorm(x, p[prefix + "w"], cfg.norm_eps)
 
 
 def norm_params(cfg, key, d, dtype):
@@ -305,6 +305,49 @@ def decode_attention(cfg, p, x, cache_k, cache_v, step, *,
     probs = jax.nn.softmax(scores, axis=-1)
     out = _gqa_out(probs, v, x.dtype)
     return out @ p["wo"], k, v
+
+
+# --------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2/V3, no q-LoRA; training only)
+# --------------------------------------------------------------------------
+
+def mla_params(cfg, key, dtype):
+    d, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], (d, H * (nope + rope)), dtype),
+        "wkv_a": dense_init(ks[1], (d, r + rope), dtype),
+        "kv_norm": {"w": jnp.ones((r,), dtype)},
+        "wkv_b": dense_init(ks[2], (r, H * (nope + vd)), dtype),
+        "wo": dense_init(ks[3], (H * vd, d), dtype),
+    }
+
+
+def mla_attention(cfg, p, x, positions):
+    """x (B, S, d) -> (B, S, d). Queries: per head a no-RoPE part and a
+    RoPE part; keys: the no-RoPE part up-projected per head from the
+    RMS-normed ``kv_lora_rank`` latent, the RoPE part one projection
+    shared by every head; values up-projected from the same latent. The
+    core (``kernels/attention.py``) takes qk and v widths as they are."""
+    from repro.kernels import attention as attn_kernel
+    B, S, _ = x.shape
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q = (x @ p["wq"]).reshape(B, S, H, nope + rope)
+    kv_a = x @ p["wkv_a"]
+    latent = rmsnorm(kv_a[..., :r], p["kv_norm"]["w"], cfg.norm_eps)
+    k_pe = apply_rope(kv_a[..., None, r:], positions, 1.0, cfg.rope_theta)
+    kv = (latent @ p["wkv_b"]).reshape(B, S, H, nope + vd)
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rope(q[..., nope:], positions, 1.0,
+                                   cfg.rope_theta)], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (B, S, H, rope))], axis=-1)
+    with jax.named_scope("mla_attn"):
+        out = attn_kernel.causal_attention(q, k, kv[..., nope:],
+                                           scale=1.0 / math.sqrt(nope + rope))
+    return out.reshape(B, S, H * vd).astype(x.dtype) @ p["wo"]
 
 
 # --------------------------------------------------------------------------

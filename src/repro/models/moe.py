@@ -9,6 +9,14 @@ laid out (E, d, ff) so the expert dim can be sharded for expert parallelism
 Aux loss is the standard Switch load-balance term
 ``E * sum_e f_e * p_e`` (f_e = fraction of tokens routed to e, p_e = mean
 router prob of e).
+
+``held_expert_ffn`` is the DeepSeek-V3 expert layer (``router="sigmoid"``)
+as one chip of an expert-parallel group runs it: it routes every token
+over all ``num_experts`` (sigmoid scores; a correction bias that only
+chooses; top-k weights renormalised and scaled), computes only the
+``experts_held`` experts from ``expert_offset`` on, dropless, with a
+grouped matmul over the rows routed to them, and adds the shared experts.
+What the absent experts would add is left to the chips that hold them.
 """
 from __future__ import annotations
 
@@ -23,6 +31,16 @@ from repro.models import layers as L
 def moe_params(cfg, key, dtype):
     E, d, ff = cfg.num_experts, cfg.d_model, cfg.d_ff
     ks = jax.random.split(key, 5)
+    if cfg.router == "sigmoid":
+        Eh = cfg.experts_held or E
+        return {
+            "router": L.dense_init(ks[0], (d, E), jnp.float32),
+            "wg": L.dense_init(ks[1], (Eh, d, ff), dtype),
+            "wu": L.dense_init(ks[2], (Eh, d, ff), dtype),
+            "wd": L.dense_init(ks[3], (Eh, ff, d), dtype),
+            "shared": L.ffn_params(cfg, ks[4], dtype,
+                                   ff=ff * cfg.shared_experts),
+        }
     p = {
         "router": L.dense_init(ks[0], (d, E), jnp.float32),
         "wg": L.dense_init(ks[1], (E, d, ff), dtype),
@@ -162,3 +180,67 @@ def moe_ffn(cfg, p, x):
     p_e = jnp.mean(gates, axis=0)
     aux = E * jnp.sum(f_e / k * p_e)
     return out.reshape(B, S, d), aux
+
+
+# --------------------------------------------------------------------------
+# sigmoid-routed experts held by one chip, dropless, plus shared experts
+# --------------------------------------------------------------------------
+
+def router_bias(cfg, n_layers: int):
+    """The routers' correction biases, (n_layers, num_experts): a seeded
+    constant outside the trained parameters, so never federated."""
+    return cfg.router_bias_std * jax.random.normal(
+        jax.random.PRNGKey(cfg.router_bias_seed),
+        (n_layers, cfg.num_experts), jnp.float32)
+
+
+def sigmoid_route(cfg, router, xt, bias):
+    """xt (T, d) -> (experts (T, k) int32, weights (T, k) f32): the top-k
+    of sigmoid score + bias; weights are the chosen sigmoid scores,
+    renormalised and times ``routed_scale``."""
+    scores = jax.nn.sigmoid(xt.astype(jnp.float32) @ router)
+    _, top = jax.lax.top_k(scores + bias, cfg.top_k)
+    w = jnp.take_along_axis(scores, top, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return top, w * cfg.routed_scale
+
+
+@jax.custom_vjp
+def _permute(x, perm, inv):
+    """x[perm] for a permutation; its backward is the gather by ``inv``."""
+    return x[perm]
+
+
+_permute.defvjp(lambda x, perm, inv: (x[perm], inv),
+                lambda inv, g: (g[inv], None, None))
+
+
+def held_expert_ffn(cfg, p, x, bias):
+    """x (B, S, d) -> (out (B, S, d), stats). Every (token, choice) pair
+    gets a row of the sorted buffer, so nothing is dropped whatever the
+    routing; the grouped matmul multiplies only the held experts' rows.
+    stats: ``expert_rows``, the rows routed to held experts."""
+    from repro.kernels import grouped_matmul
+    B, S, d = x.shape
+    T, k, Eh = B * S, cfg.top_k, cfg.experts_held or cfg.num_experts
+    xt = x.reshape(T, d)
+    with jax.named_scope("moe_route"):
+        top, w = sigmoid_route(cfg, p["router"], xt, bias)
+        local = top.reshape(T * k) - cfg.expert_offset
+        held = (local >= 0) & (local < Eh)
+        group = jnp.where(held, local, Eh)
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        sizes = jnp.bincount(group, length=Eh + 1)[:Eh].astype(jnp.int32)
+        xs = _permute(jnp.repeat(xt, k, axis=0), order, inv)
+        ws = jnp.where(held, w.reshape(T * k), 0.0)[order]
+    with jax.named_scope("moe_experts"):
+        gmm = grouped_matmul.expert_matmul
+        h = jax.nn.silu(gmm(xs, p["wg"], sizes)) * gmm(xs, p["wu"], sizes)
+        ys = gmm(h, p["wd"], sizes) * ws[:, None]
+        routed = _permute(ys, inv, order).reshape(T, k, d).sum(axis=1)
+    with jax.named_scope("moe_shared"):
+        shared = L.ffn(cfg, p["shared"], xt)
+    stats = {"expert_rows": jnp.sum(sizes)}
+    return (routed.astype(x.dtype) + shared).reshape(B, S, d), stats

@@ -121,7 +121,7 @@ def forward(params, batch, cfg, *, return_cache: bool = False):
 
 def loss_fn(params, batch, cfg):
     logits, _, _ = forward(params, batch, cfg)
-    return L.softmax_xent(logits[:, :-1], batch["labels"][:, 1:])
+    return L.softmax_xent(logits, batch["labels"])
 
 
 def prefill(params, batch, cfg):

@@ -103,3 +103,52 @@ def test_vmapped_kernel_compiles_for_v5e(one_chip, name):
     batched = [((2,) + s, d) for s, d in shapes]
     compiled = _compile(jax.vmap(fn), batched, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# moonlight-16b-a3b-ep8: one MoE layer's buffer of (token, choice) rows at
+# 4,096 tokens × top-6, its 8 held experts, and the latent attention's
+# 16 heads of 192-wide queries/keys and 128-wide values; its arena is
+# 555,161 rows and its cohort 2
+MOONLIGHT_ROWS = 555_161
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    from repro.kernels import backend
+    monkeypatch.setattr(backend, "resolve", lambda: "tpu-pallas")
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+def test_expert_matmul_compiles_for_v5e(one_chip, tpu_backend, k, n):
+    from repro.kernels import grouped_matmul as gm
+
+    def loss(lhs, rhs, sizes):
+        return jnp.sum(gm.expert_matmul(lhs, rhs, sizes))
+    compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1)),
+                        [((4096 * 6, k), jnp.float32),
+                         ((8, k, n), jnp.float32), ((8,), jnp.int32)],
+                        one_chip)
+    text = compiled.as_text()
+    assert text.count("gmm") > text.count("tgmm") > 0
+
+
+def test_causal_attention_compiles_for_v5e(one_chip, tpu_backend):
+    from repro.kernels import attention
+
+    def loss(q, k, v):
+        return jnp.sum(attention.causal_attention(q, k, v, scale=0.07))
+    compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                        [((1, 4096, 16, 192), jnp.float32),
+                         ((1, 4096, 16, 192), jnp.float32),
+                         ((1, 4096, 16, 128), jnp.float32)], one_chip)
+    text = compiled.as_text()
+    for kernel in ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv"):
+        assert kernel in text
+
+
+@pytest.mark.parametrize("name", ["masked_agg", "per_client_sign_align"])
+def test_arena_kernel_compiles_at_moonlight_rows(one_chip, name):
+    fn, shapes = _kernel_cases(MOONLIGHT_ROWS)[name]
+    shapes = [((2,) + s[1:], d) if s[0] == C else (s, d) for s, d in shapes]
+    compiled = _compile(fn, shapes, one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
