@@ -44,6 +44,7 @@ from repro.api.spec import ExperimentSpec
 from repro.core import async_engine as ae
 from repro.core import compression, fl_step
 from repro.core import megastep as megastep_mod
+from repro.core import records as records_mod
 from repro.core import scenario as scenario_mod
 from repro.data.loader import ArrayLoader
 from repro.kernels import arena as arena_mod
@@ -561,11 +562,11 @@ def run_scanned_seed_batch(spec: ExperimentSpec,
     an S-seed sweep cell of N rounds costs ``ceil(N / R)`` compiled
     dispatches TOTAL — no per-seed, per-dispatch eval readback breaks
     the stream; per-round metrics buffer on device and drain in one
-    ``device_get`` at the end. Per-seed worlds (data, profiles, control
-    state, PRNG keys) stack along a leading seed axis; the per-client
-    sample capacity pads to the cross-seed maximum, which never changes
-    a trajectory because batch index sampling is bounded by each seed's
-    true shard sizes. Requires every seed to resolve the same scanned
+    ``device_get`` at the end. Per-seed worlds (record tables, profiles,
+    control state, PRNG keys) stack along a leading seed axis; each
+    seed's record table pads with zero rows to the longest, which never
+    changes a trajectory because a seed's batch rows lie inside its own
+    records. Requires every seed to resolve the same scanned
     trace shape (select_k, steps_phys, batch_phys).
     """
     t0 = time.time()
@@ -590,18 +591,7 @@ def run_scanned_seed_batch(spec: ExperimentSpec,
     S = len(sims)
 
     # --- stack the per-seed device worlds along a leading seed axis ---
-    def _pad_cap(a, cap):
-        pad = cap - a.shape[1]
-        if pad == 0:
-            return a
-        widths = [(0, 0)] * a.ndim
-        widths[1] = (0, pad)
-        return jnp.pad(a, widths)
-
-    keys = list(sims[0]._scan_world[0])
-    cap = max(sim._scan_world[0][keys[0]].shape[1] for sim in sims)
-    data = {k: jnp.stack([_pad_cap(sim._scan_world[0][k], cap)
-                          for sim in sims]) for k in keys}
+    data = records_mod.stack([sim._scan_world[0] for sim in sims])
     sizes, speed, latency, dropout_p = (
         jnp.stack([sim._scan_world[1 + a] for sim in sims])
         for a in range(4))

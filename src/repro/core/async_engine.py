@@ -55,6 +55,7 @@ import numpy as np
 from repro.core import aggregation, alignment, compression
 from repro.core import control as control_mod
 from repro.core import megastep as megastep_mod
+from repro.core import records as records_mod
 from repro.core import scenario as scenario_mod
 from repro.core.batchsize import BatchSizeController, ClientMetrics
 from repro.core.checkpoint_policy import fit_weibull, optimal_interval
@@ -339,7 +340,7 @@ class FederatedSimulation:
 
         # --- device-resident control plane (scanned path, built lazily) ---
         self._scan_fns: Dict[int, Callable] = {}   # R -> jitted scan
-        self._scan_world = None                    # (data, sizes, profiles)
+        self._scan_world = None          # (record table, sizes, profiles)
         self._scan_ctl = None                      # ControlState carry
         self._scan_ref_valid = jnp.asarray(False)
         self._scan_round0 = 0
@@ -933,16 +934,7 @@ class FederatedSimulation:
                 "the scanned control plane stacks the full population "
                 "device-side; non-resident worlds run the loop/megastep "
                 "paths")
-        cap = max(l.n for l in self.loaders)
-        data = {}
-        for k in self.loaders[0].arrays:
-            stacked = []
-            for l in self.loaders:
-                a = np.asarray(l.arrays[k])
-                pad = np.zeros((cap - len(a),) + a.shape[1:], a.dtype)
-                stacked.append(np.concatenate([a, pad]) if len(pad)
-                               else a)
-            data[k] = jnp.asarray(np.stack(stacked))
+        data = records_mod.pack([l.arrays for l in self.loaders])
         sizes = jnp.asarray([l.n for l in self.loaders], jnp.int32)
         speed = jnp.asarray([p.speed for p in self.profiles], jnp.float32)
         latency = jnp.asarray([p.net_latency for p in self.profiles],

@@ -228,7 +228,9 @@ def build_scanned_rounds(cfg, opt, arena, st, comm, *, num_clients: int,
     ``TopologyRuntime`` — is not attached); its sync cadence is a closed
     form on the absolute round index, so it is likewise R-independent.
     ``acc`` is the (sim_time, comm_time, idle_time, bytes_sent) f32
-    accumulator vector.
+    accumulator vector. ``data`` is the population's records, one
+    ``records.RecordTable``: a round reads its cohort batch from it
+    with one row gather.
 
     Whole-experiment fusion (``eval_fn`` not None): evaluation joins the
     scan carry instead of breaking the dispatch stream. ``eval_fn`` must
@@ -323,12 +325,12 @@ def build_scanned_rounds(cfg, opt, arena, st, comm, *, num_clients: int,
                 delay = jnp.zeros((K,), jnp.float32)
 
         with jax.named_scope("fl_gather"):
-            # --- cohort batches: on-device gather + index sampling ------
+            # --- cohort batches: index sampling + one row gather from
+            # the packed record table (core/records.py) ------------------
             sz = sizes[cohort]
             idx = jax.random.randint(k_data, (K, steps_phys, batch_phys), 0,
                                      sz[:, None, None])
-            batch = {name: leaf[cohort[:, None, None], idx]
-                     for name, leaf in data.items()}
+            batch = data.take(cohort, idx)
             if dirs is not None:
                 batch = scenario_mod.apply_drift(batch, ws.drift_amp, dirs,
                                                  drift_label)
@@ -516,7 +518,7 @@ def scan_donate_argnums(*, fused: bool, donate=None):
     accounting accumulator — plus ``prev_acc`` when eval is fused) are
     consumed and rebound from the scan output by every caller, so their
     input buffers can be reused in place. The read-only population
-    stacks (data/sizes/speed/latency/dropout_p) and the PRNG key are
+    (record table, sizes, speed, latency, dropout_p) and the PRNG key are
     never donated. CPU ignores donation with a warning, so the default
     donates only where the platform honors it.
     """

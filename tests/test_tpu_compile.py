@@ -152,3 +152,38 @@ def test_arena_kernel_compiles_at_moonlight_rows(one_chip, name):
     shapes = [((2,) + s[1:], d) if s[0] == C else (s, d) for s, d in shapes]
     compiled = _compile(fn, shapes, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_packed_record_gather_compiles_for_v5e(one_chip):
+    """Silo's cohort batch (10 silos × 16 steps × 256 rows = 40,960
+    indices) from its packed record table (175,341 records of 49 f32
+    features and an s32 label, and the zero row): one gather of whole
+    rows under ``fl_gather``, and no gather of labels one by one."""
+    import re
+
+    import numpy as np
+
+    from repro.core import records
+    leaves = (records.Leaf("x", np.dtype(np.float32), (49,), 0, 49),
+              records.Leaf("y", np.dtype(np.int32), (), 49, 1))
+
+    def batch_loss(table, offsets, cohort, idx, w):
+        with jax.named_scope("fl_gather"):
+            batch = records.RecordTable(table, offsets, leaves).take(
+                cohort, idx)
+        # local training's use of them: features into a matmul, labels
+        # picking a logit
+        logits = batch["x"] @ w
+        return jnp.sum(jnp.take_along_axis(logits, batch["y"][..., None],
+                                           axis=-1))
+
+    compiled = _compile(batch_loss, [((175_342, 50), jnp.uint32),
+                                     ((10,), jnp.int32), ((10,), jnp.int32),
+                                     ((10, 16, 256), jnp.int32),
+                                     ((49, 10), jnp.float32)], one_chip)
+    gathers = [line for line in compiled.as_text().splitlines()
+               if " gather(" in line and "/fl_gather/" in line]
+    rows = [g for g in gathers if re.search(r"= \w+\[40960[,\]]", g)]
+    assert len(rows) == 1, rows
+    assert "u32[40960,50]" in rows[0]
+    assert not any("s32[40960]" in g for g in gathers)
